@@ -16,10 +16,9 @@ Two climbs share one scoring contract:
 
 Run AFTER the main dry-run sweep:  PYTHONPATH=src python -m benchmarks.hillclimb
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import dataclasses
 import json
+import os
 
 
 def lsm_score(c: float, T: float, pin_frac: float, n: int = 20_000,
@@ -90,7 +89,9 @@ def show(tag, r):
 
 def main():
     from repro.configs import get_config
-    from repro.launch.dryrun import run_cell
+    from repro.launch.dryrun import HOST_DEVICES_FLAG, run_cell
+
+    os.environ["XLA_FLAGS"] = HOST_DEVICES_FLAG   # before any device query
 
     # ---- Cell A: minicpm prefill ------------------------------------------
     print("[A] minicpm_2b / prefill_32k")

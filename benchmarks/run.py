@@ -14,11 +14,14 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (bloom_opt, complexity_check, micro_dbbench, roofline,
                sensitivity_ct, ycsb)
 
 
 def main() -> None:
+    enable_compile_cache()
     args = [a for a in sys.argv[1:]]
     scale = 1.0
     for flag, s in (("--quick", 0.25), ("--full", 10.0)):

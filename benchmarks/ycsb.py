@@ -187,8 +187,7 @@ def run(n: int = 60_000, n_ops: int = 8_000) -> List[Dict]:
                 row["Cbatch_kops"] = mb["kops"]
                 row["Cbatch_speedup"] = (mb["kops"] / m["kops"]
                                          if m["kops"] else 0.0)
-                # same stream again through the Pallas bloom-probe route
-                # (falls back to numpy when jax is unavailable)
+                # same stream again through the device bloom-probe route
                 db.config.use_pallas_bloom = True
                 row["Cbatch_pallas_kops"] = _mix_batched_reads(
                     db, n, n_ops)["kops"]

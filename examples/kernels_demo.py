@@ -1,24 +1,25 @@
-"""Pallas kernel demo: the paper's hot paths on TPU-shaped kernels
-(interpret mode on CPU; pass interpret=False on a real TPU).
+"""Device kernel demo: the paper's hot paths on TPU-shaped kernels.  They
+run compiled on a TPU and in interpret mode on the CPU, picked from the
+default backend.
 
     PYTHONPATH=src python examples/kernels_demo.py
 """
 import numpy as np
 import jax.numpy as jnp
 
-from repro.kernels import (bloom_probe, flash_attention, merge_runs_tiled,
-                           paged_attention, ops)
+from repro.core import BloomFilter
+from repro.kernels import (bloom_probe_filter, flash_attention,
+                           merge_runs_tiled, paged_attention)
 from repro.kernels import ref
 
 rng = np.random.default_rng(0)
 
-# 1. bloom_probe: the point-read filter pass (paper §3.1 CPU optimization)
+# 1. bloom probe: the point-read filter pass (paper §3.1 CPU optimization)
 members = rng.integers(0, 2**62, 4096, dtype=np.uint64)
-lo, hi = ops.split_u64(members)
-bits = ref.bloom_build_ref(np.asarray(lo), np.asarray(hi), m_words=2048,
-                           k_hashes=7)
+bf = BloomFilter(members, bits_per_key=16)
 absent = rng.integers(2**62, 2**63, 4096, dtype=np.uint64)
-fpr = float(np.mean(np.asarray(bloom_probe(absent, jnp.asarray(bits), 7))))
+assert bloom_probe_filter(bf, members).all()
+fpr = float(np.mean(bloom_probe_filter(bf, absent)))
 print(f"bloom_probe      : members all hit, absent FPR={fpr:.4f}")
 
 # 2. merge_path: bitonic compaction merge (two sorted runs -> one)

@@ -2,11 +2,11 @@
 
 ``BloomFilter`` is a vectorized double-hashing bloom filter over uint64 keys.
 Bit positions are computed with the *same* 32-bit murmur-style hash family as
-the Pallas batched-probe kernel (``repro.kernels.bloom_probe.hash_pair``) and
-the bitset is stored as uint32 words, so the engine's batched read path can
-probe the identical filter either in numpy (``may_contain``) or on the VPU
-(``repro.kernels.ops.bloom_probe_filter``) and get bit-identical answers
-(DESIGN.md §3).
+the device batched probe (``repro.kernels.bloom_probe.hash_pair``) and the
+bitset is stored as uint32 words, so the engine's batched read path can
+probe the identical filter either in numpy (``may_contain``) or on the
+device (``repro.kernels.ops.bloom_probe_filter``) and get bit-identical
+answers (DESIGN.md §3).
 
 ``allocate_fprs`` solves the Monkey optimization adapted to Garnering: minimize
 the zero-result point-read cost R = sum_i p_i subject to the total filter
@@ -39,7 +39,7 @@ def _mix32(x: np.ndarray, c1: int, c2: int) -> np.ndarray:
 
 def hash_pair(keys: np.ndarray):
     """Two independent uint32 hashes of u64 keys — identical positions to the
-    Pallas kernel's ``hash_pair`` on the (lo, hi) halves."""
+    device probe's ``hash_pair`` on the (lo, hi) halves."""
     keys = np.asarray(keys, dtype=np.uint64)
     lo = (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     hi = (keys >> np.uint64(32)).astype(np.uint32)
@@ -56,8 +56,8 @@ def build_bits(h1: np.ndarray, h2: np.ndarray, k: int, m_bits: int
 
     All ``k * n`` double-hash positions are computed at once, scattered into
     a boolean bit map (duplicate positions collapse for free), and packed
-    little-endian — the exact word/bit layout ``may_contain`` and the Pallas
-    probe kernel index.  Replaces the k-iteration ``np.bitwise_or.at`` loop,
+    little-endian — the exact word/bit layout ``may_contain`` and the device
+    probe index.  Replaces the k-iteration ``np.bitwise_or.at`` loop,
     which is unbuffered and dominates compaction's filter-rebuild cost.
     """
     ks = np.arange(k, dtype=np.uint32)[:, None]
@@ -74,7 +74,7 @@ class BloomFilter:
     """Standard bloom filter with k = round(bits_per_key * ln2) double hashes.
 
     ``bits`` is a uint32 word array with m_bits == 32 * len(bits), the exact
-    layout ``bloom_probe_pallas`` consumes.
+    layout the device probe (``kernels.ops.bloom_probe_filter``) consumes.
     """
 
     __slots__ = ("m_bits", "k", "bits", "n_keys")
@@ -92,8 +92,7 @@ class BloomFilter:
             self.k = 0
             self.bits = np.zeros(0, dtype=np.uint32)
             return
-        # Round up to whole uint32 words: the Pallas kernel derives m from the
-        # word count, so numpy and VPU probes must agree on m exactly.
+        # Round up to whole uint32 words, the unit both probes index.
         m = -(-max(64, int(round(bits_per_key * n))) // 32) * 32
         self.m_bits = m
         self.k = max(1, int(round(bits_per_key * LN2)))

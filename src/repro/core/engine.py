@@ -51,7 +51,6 @@ from .types import (BLOCK_SIZE, KEY_BYTES, KEY_DTYPE, SEQ_DTYPE,
                     TOMBSTONE_LEN, IOStats, StatsHub)
 from .view import RangeView, build_range_view
 
-_UNSET = object()
 # Soft write-pressure delay.  LevelDB sleeps 1 ms here, but its pressure unit
 # is a 4 MB L0 file; ours is a ~32 KB memtable whose whole fill takes well
 # under 1 ms — and on coarse-tick kernels (CONFIG_HZ=100) any nonzero sleep
@@ -76,11 +75,11 @@ class LSMConfig:
     block_size: int = BLOCK_SIZE
     key_bytes: int = KEY_BYTES
     use_pallas_bloom: bool = False      # route multi_get probes AND filter
-                                        # rebuilds through the Pallas hash
-                                        # family (numpy when unavailable)
+                                        # rebuilds through the device hash
+                                        # family (kernels.ops)
     use_pallas_merge: bool = False      # route compaction's pairwise merges
                                         # through the bitonic merge-path
-                                        # kernel (numpy when unavailable)
+                                        # kernel (kernels.ops)
     cache_bytes: int = 0                # block cache budget; 0 => no cache
     pin_l0_bytes: int = 0               # DRAM-resident L0 budget (paper's
                                         # "bounded space of DRAM"); 0 => none
@@ -218,9 +217,6 @@ class LSMStore:
         # *rejection*, not the surfacing — it can fire many times without
         # consuming the one loud raise of the underlying failure.
         self._bg_failure_surfaced = False
-        self._pallas_probe_fn = _UNSET  # lazy: resolved on first multi_get
-        self._pallas_hash_fn = _UNSET   # lazy: resolved on first filter build
-        self._pallas_merge_fn = _UNSET  # lazy: resolved on first compaction
         # Async compaction (DESIGN.md §11): rotated memtables queue here
         # (oldest first) and stay readable until their background flush
         # installs; the maintenance lock serializes the gc+retain+repin
@@ -1229,59 +1225,40 @@ class LSMStore:
                 return value
         return None
 
-    def _bloom_probe_fn(self):
-        """Resolve the Pallas batched-probe route (numpy fallback).
+    # The device routes are imported on first use, so a store that never
+    # turns them on never imports jax.  The config flags are re-read on every
+    # call, so toggling them on a live store takes effect.  A device path
+    # that fails raises: there is no silent numpy fallback.
 
-        The config flag is re-read every call so toggling
-        ``use_pallas_bloom`` on a live store takes effect; only the import
-        result is cached.
-        """
+    def _bloom_probe_fn(self):
+        """The device batched-probe route (``kernels.ops.bloom_probe_filter``)
+        when ``use_pallas_bloom`` is on, else None (numpy probes)."""
         if not self.config.use_pallas_bloom:
             return None
-        if self._pallas_probe_fn is _UNSET:
-            try:
-                from repro.kernels.ops import bloom_probe_filter
-                self._pallas_probe_fn = bloom_probe_filter
-            except Exception:       # jax/pallas unavailable: stay on numpy
-                self._pallas_probe_fn = None
-        return self._pallas_probe_fn
+        from repro.kernels.ops import bloom_probe_filter
+        return bloom_probe_filter
 
     def _bloom_hash_fn(self):
-        """Resolve the Pallas filter-*build* hash route (numpy fallback).
-
-        Shares the ``use_pallas_bloom`` toggle with the probe route: when
-        on, flush and compaction rebuild output filters from one device-side
-        hash pass (``kernels.ops.bloom_build_hashes``) that is bit-identical
-        to the numpy family, so either backend may probe the result.
-        """
+        """The device filter-*build* hash route, sharing the
+        ``use_pallas_bloom`` toggle with the probe route: flush and
+        compaction rebuild output filters from one device-side hash pass
+        (``kernels.ops.bloom_build_hashes``) that is bit-identical to the
+        numpy family, so either backend may probe the result."""
         if not self.config.use_pallas_bloom:
             return None
-        if self._pallas_hash_fn is _UNSET:
-            try:
-                from repro.kernels.ops import bloom_build_hashes
-                self._pallas_hash_fn = bloom_build_hashes
-            except Exception:       # jax/pallas unavailable: stay on numpy
-                self._pallas_hash_fn = None
-        return self._pallas_hash_fn
+        from repro.kernels.ops import bloom_build_hashes
+        return bloom_build_hashes
 
     def _pair_merge_fn(self):
-        """Resolve the Pallas merge-path compaction lane (numpy fallback).
-
-        When ``use_pallas_merge`` is on, every pairwise merge of the
-        compaction ladder routes through ``kernels.ops.merge_runs_tiled``
-        (merge-path partition + bitonic network; interpret mode on CPU, the
-        same BlockSpecs lower via Mosaic on TPU).  Differentially tested
-        bit-for-bit against the numpy ladder and ``merge_runs_scalar``.
-        """
+        """The device merge-path compaction lane when ``use_pallas_merge``
+        is on: every pairwise merge of the compaction ladder routes through
+        ``kernels.ops.merge_runs_tiled`` (merge-path partition + bitonic
+        network; compiled on TPU, interpreted on CPU).  Differentially tested
+        bit-for-bit against the numpy ladder and ``merge_runs_scalar``."""
         if not self.config.use_pallas_merge:
             return None
-        if self._pallas_merge_fn is _UNSET:
-            try:
-                from repro.kernels.ops import merge_runs_tiled
-                self._pallas_merge_fn = merge_runs_tiled
-            except Exception:       # jax/pallas unavailable: stay on numpy
-                self._pallas_merge_fn = None
-        return self._pallas_merge_fn
+        from repro.kernels.ops import merge_runs_tiled
+        return merge_runs_tiled
 
     def multi_get(self, keys: Sequence[int],
                   snapshot: Optional[Version] = None) -> List[Optional[bytes]]:
@@ -1289,7 +1266,7 @@ class LSMStore:
 
         The batch is resolved level by level: every still-pending key is
         bloom-probed against a run in one vectorized pass (optionally through
-        the Pallas kernel, DESIGN.md §3) and located with one searchsorted
+        the device probe, DESIGN.md §3) and located with one searchsorted
         over the run's fence-pointed key array.  Aggregate IOStats accounting
         is identical to the equivalent sequence of scalar ``get`` calls.
         """

@@ -356,7 +356,7 @@ class Memtable:
         Values are joined into one flat byte buffer and scattered into the
         padded value matrix with a single fancy-index write; the run
         inherits this memtable's ``block_size``/``key_bytes``.  ``hash_fn``
-        reroutes the bloom build's hash pass (engine's Pallas route).
+        reroutes the bloom build's hash pass (engine's device route).
         """
         n = len(self._data)
         keys = np.fromiter(self._data.keys(), dtype=KEY_DTYPE, count=n)

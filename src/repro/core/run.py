@@ -213,7 +213,7 @@ class SortedRun:
         tombstone).  One bloom pass + one searchsorted over the whole batch;
         aggregate IOStats accounting is identical to len(keys) scalar
         ``point_get`` calls.  ``probe_fn(bloom, keys) -> bool mask`` optionally
-        reroutes the filter probe (e.g. through the Pallas kernel); ``cache``
+        reroutes the filter probe (e.g. through the device probe); ``cache``
         routes the candidate block reads through the block cache, in batch
         order (so two candidates sharing a block cost one miss + one hit).
         """
@@ -339,7 +339,7 @@ def build_run(keys: np.ndarray, seqs: np.ndarray, vlens: np.ndarray,
 
     ``block_size``/``key_bytes`` shape the constructed run's block layout
     (threaded from ``LSMConfig`` by the engine); ``hash_fn`` optionally
-    reroutes the bloom build's hash pass (e.g. through the Pallas kernel
+    reroutes the bloom build's hash pass (e.g. through the device hash
     family — see ``core.bloom.BloomFilter``).
     """
     keys = np.asarray(keys, dtype=KEY_DTYPE)

@@ -1,25 +1,24 @@
-"""Pallas TPU kernel: batched bloom-filter probe (the point-read CPU hotspot).
+"""Device bloom-filter probe (the point-read filter pass, paper §3.1).
 
-The paper (§3.1 "CPU Optimization") argues filter probing is the emerging
-point-read bottleneck; Autumn reduces probe count via fewer levels, and this
-kernel makes each batch of probes one VPU pass: queries are tiled into VMEM
-blocks, the k double-hashes are computed vectorially (splitmix64 on two u32
-lanes — the TPU VPU has no u64 lanes), and the bitset is held in VMEM.
+The paper argues filter probing is the emerging point-read bottleneck;
+Autumn reduces probe count via fewer levels, and this pass makes each batch
+of probes one device program: the k double-hashes are computed vectorially
+(two 32-bit murmur-style mixes on the (lo, hi) halves of each u64 key — the
+TPU vector unit has no u64 lanes) and each probe gathers one u32 word from
+the bitset in HBM.
 
-TPU adaptation notes (DESIGN.md §2): the per-probe random bitset access is a
-dynamic gather; on TPU we express it as `jnp.take` over the VMEM-resident
-bitset (Mosaic lowers small-table dynamic gathers; filters larger than VMEM
-are probed level-by-level by ops.py, matching Monkey's per-level filters).
+It is a plain jitted XLA function, not a Pallas kernel: Mosaic lowers only
+2-D gathers within a vreg, so a random word gather over a whole filter
+cannot be written there, while XLA's gather reads it straight from HBM with
+no cap on filter size.  ``m_bits`` and the hash count are operands, so the
+bitset can be padded to a bucketed length (``ops.bloom_probe_filter``)
+without changing any bit position, and one compile serves every level's
+hash count.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-QUERY_BLOCK = 512
 
 
 def _mix32(x: jnp.ndarray, c1: int, c2: int) -> jnp.ndarray:
@@ -42,40 +41,19 @@ def hash_pair(keys_lo: jnp.ndarray, keys_hi: jnp.ndarray):
     return h1, h2
 
 
-def bloom_probe_kernel(lo_ref, hi_ref, bits_ref, out_ref, *, k_hashes: int,
-                       m_bits: int):
-    lo = lo_ref[...]
-    hi = hi_ref[...]
-    h1, h2 = hash_pair(lo, hi)
-    maybe = jnp.ones(lo.shape, jnp.bool_)
-    m = jnp.uint32(m_bits)
-    bits = bits_ref[...]
-    for i in range(k_hashes):
-        pos = (h1 + jnp.uint32(i) * h2) % m
-        word = jnp.take(bits, (pos >> jnp.uint32(5)).astype(jnp.int32))
-        maybe &= ((word >> (pos & jnp.uint32(31))) & jnp.uint32(1)) != 0
-    out_ref[...] = maybe
+def bloom_probe(keys_lo: jax.Array, keys_hi: jax.Array, bits: jax.Array,
+                m_bits: jax.Array, k_hashes: jax.Array) -> jax.Array:
+    """keys_lo/hi: (N,) uint32; bits: (W,) uint32 words with 32 * W >= m_bits;
+    m_bits: () uint32; k_hashes: () int32.  Returns (N,) bool 'maybe
+    present'.  Both scalars are operands, so filters of one padded length
+    share a compile whatever their hash count."""
+    h1, h2 = hash_pair(keys_lo, keys_hi)
 
+    def probe(i, maybe):
+        pos = (h1 + i.astype(jnp.uint32) * h2) % m_bits
+        word = bits[(pos >> jnp.uint32(5)).astype(jnp.int32)]
+        return maybe & (((word >> (pos & jnp.uint32(31))) & jnp.uint32(1))
+                        != 0)
 
-def bloom_probe_pallas(keys_lo: jax.Array, keys_hi: jax.Array,
-                       bits: jax.Array, k_hashes: int,
-                       interpret: bool = True) -> jax.Array:
-    """keys_lo/hi: (N,) uint32; bits: (W,) uint32 bitset. Returns (N,) bool."""
-    n = keys_lo.shape[0]
-    m_bits = bits.shape[0] * 32
-    block = min(QUERY_BLOCK, n)
-    assert n % block == 0, (n, block)
-    grid = (n // block,)
-    return pl.pallas_call(
-        functools.partial(bloom_probe_kernel, k_hashes=k_hashes,
-                          m_bits=m_bits),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec(bits.shape, lambda i: (0,)),  # bitset: whole in VMEM
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
-        interpret=interpret,
-    )(keys_lo, keys_hi, bits)
+    return jax.lax.fori_loop(0, k_hashes, probe,
+                             jnp.ones(keys_lo.shape, jnp.bool_))

@@ -66,7 +66,7 @@ def flash_attention_kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """q: (B,Sq,H,dh); k/v: (B,Sk,KH,dh). Returns (B,Sq,H,dh)."""
     B, Sq, H, dh = q.shape
     Sk, KH = k.shape[1], k.shape[2]

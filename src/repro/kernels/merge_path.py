@@ -4,94 +4,84 @@ Hardware adaptation (DESIGN.md §2): a CPU/GPU merge walks two cursors
 (branchy, serial) or binary-searches a merge path (dynamic control flow).
 Neither maps to the TPU VPU.  Instead we use the classic bitonic-merge
 network: concat(A, reverse(B)) of two sorted tiles is a bitonic sequence,
-and log2(2T) static compare-exchange stages — pure jnp.minimum/maximum over
-VMEM tiles with *static* strides — sort it.  Payloads (value indices) ride
-along through the same selects, so the engine can permute value rows after
-the kernel returns.
+and log2(2T) static compare-exchange stages sort it.  Payloads (value
+indices) ride along through the same selects, so the engine can permute
+value rows after the kernel returns.
 
-Keys are carried as *two u32 lanes* (hi, lo) compared lexicographically —
-the VPU has no u64 lanes, exactly the split the bloom-probe kernel makes —
-so the engine's uint64 user keys merge exactly (u32 callers pass hi = 0).
+Layout, chosen so Mosaic lowers it:
+
+* each tile pair is one row of 2T lanes, already concat(A, reverse(B)) —
+  the host packs the tiles (``ops.merge_runs_tiled``) and reverses B there;
+* a grid step takes ``ROWS`` rows, so every block is (8, 2T): the last two
+  dimensions are multiples of (8, 128) whenever T is a multiple of 64;
+* a stage at stride s finds each lane's partner (lane XOR s) with two lane
+  rotations (``pltpu.roll``) and picks between them with lane-iota masks, so
+  no reshape or reversal runs on the device;
+* keys travel as two lanes (hi, lo) and the payload as a third, all int32
+  with the sign bit flipped on the host, so signed compares give unsigned
+  order (Mosaic has no unsigned min/max); the lexicographic
+  (hi, lo, payload) compare makes the network deterministic.
 
 ops.py composes multi-tile runs: tile boundaries are partitioned with the
 host-side :func:`merge_path_partition` (one vectorized ``np.searchsorted``
 pass instead of a per-diagonal binary-search loop), and each pair of
-partitions is merged by one grid cell.  The same BlockSpecs drive interpret
-mode on CPU and Mosaic lowering on TPU.
+partitions is merged by one row.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+ROWS = 8   # tile pairs per grid step: the sublane count of one vreg
 
 
-def _compare_exchange(hi: jnp.ndarray, lo: jnp.ndarray, payload: jnp.ndarray,
-                      stride: int):
-    """One bitonic stage over (2T,) tiles: static-stride compare-exchange of
-    lexicographic (hi, lo, payload) triples.  The payload tie-break makes
-    the network deterministic AND orders tile pads (payload 0xFFFFFFFF,
-    larger than any real source index) strictly after real entries sharing
-    their key — so even a real key equal to the dtype maximum cannot be
-    displaced by padding."""
-    n = hi.shape[0]
-
-    def split(x):
-        x2 = x.reshape(n // (2 * stride), 2, stride)
-        return x2[:, 0], x2[:, 1]
-
-    hi_l, hi_r = split(hi)
-    lo_l, lo_r = split(lo)
-    p_l, p_r = split(payload)
-    keys_eq = (hi_l == hi_r) & (lo_l == lo_r)
-    swap = (hi_l > hi_r) | ((hi_l == hi_r) & (lo_l > lo_r)) \
-        | (keys_eq & (p_l > p_r))
-
-    def merge(l, r):
-        new_l = jnp.where(swap, r, l)
-        new_r = jnp.where(swap, l, r)
-        return jnp.stack([new_l, new_r], axis=1).reshape(n)
-
-    return merge(hi_l, hi_r), merge(lo_l, lo_r), merge(p_l, p_r)
+def _greater(a, b):
+    """Lexicographic (hi, lo, payload) a > b over int32 lanes."""
+    (ah, al, ap), (bh, bl, bp) = a, b
+    return (ah > bh) | ((ah == bh) & ((al > bl) | ((al == bl) & (ap > bp))))
 
 
-def bitonic_merge_kernel(a_hi_ref, a_lo_ref, b_hi_ref, b_lo_ref,
-                         pa_ref, pb_ref, ohi_ref, olo_ref, op_ref,
-                         *, tile: int):
-    """Merge two sorted (T,) tiles (split-u64 keys + payloads) into (2T,)."""
-    hi = jnp.concatenate([a_hi_ref[...], b_hi_ref[...][::-1]])
-    lo = jnp.concatenate([a_lo_ref[...], b_lo_ref[...][::-1]])
-    payload = jnp.concatenate([pa_ref[...], pb_ref[...][::-1]])
-    stride = tile
+def bitonic_merge_kernel(hi_ref, lo_ref, p_ref, ohi_ref, olo_ref, op_ref):
+    """Sort every row of a (ROWS, 2T) block; each row is bitonic on entry."""
+    x = (hi_ref[...], lo_ref[...], p_ref[...])
+    width = x[0].shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x[0].shape, 1)
+    stride = width // 2
     while stride >= 1:
-        hi, lo, payload = _compare_exchange(hi, lo, payload, stride)
+        # Which rotation brings lane XOR stride to each lane is read off a
+        # rotated iota, so the network holds whatever direction roll uses.
+        from_roll = pltpu.roll(lane, stride, 1) == (lane ^ stride)
+        partner = tuple(jnp.where(from_roll, pltpu.roll(v, stride, 1),
+                                  pltpu.roll(v, width - stride, 1))
+                        for v in x)
+        is_low = (lane & stride) == 0
+        # the low lane of a pair keeps the smaller entry, the high the larger
+        swap = (is_low & _greater(x, partner)) \
+            | (~is_low & _greater(partner, x))
+        x = tuple(jnp.where(swap, p, v) for v, p in zip(x, partner))
         stride //= 2
-    ohi_ref[...] = hi
-    olo_ref[...] = lo
-    op_ref[...] = payload
+    ohi_ref[...], olo_ref[...], op_ref[...] = x
 
 
-def bitonic_merge_pallas(a_hi: jax.Array, a_lo: jax.Array, b_hi: jax.Array,
-                         b_lo: jax.Array, pa: jax.Array, pb: jax.Array,
-                         interpret: bool = True):
-    """a/b: sorted (n, T) tile batches as (hi, lo) u32 lanes; pa, pb: u32
-    payloads.  Returns merged (n, 2T) key lanes + payloads — one grid cell
-    per tile pair."""
-    n, tile = a_lo.shape
-    kern = functools.partial(bitonic_merge_kernel, tile=tile)
+def bitonic_merge_pallas(hi: jax.Array, lo: jax.Array, payload: jax.Array, *,
+                         interpret: bool):
+    """hi, lo, payload: (n, 2T) int32 rows, each concat(A, reverse(B)) of two
+    sorted tiles, n a multiple of ``ROWS``.  Returns the three lanes with
+    every row sorted — one grid step per ``ROWS`` tile pairs."""
+    n, width = hi.shape
+    spec = pl.BlockSpec((ROWS, width), lambda i: (i, 0))
     return pl.pallas_call(
-        kern,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((None, tile), lambda i: (i, 0))] * 6,
-        out_specs=[pl.BlockSpec((None, 2 * tile), lambda i: (i, 0))] * 3,
-        out_shape=[jax.ShapeDtypeStruct((n, 2 * tile), a_hi.dtype),
-                   jax.ShapeDtypeStruct((n, 2 * tile), a_lo.dtype),
-                   jax.ShapeDtypeStruct((n, 2 * tile), pa.dtype)],
+        bitonic_merge_kernel,
+        grid=(n // ROWS,),
+        in_specs=[spec] * 3,
+        out_specs=[spec] * 3,
+        out_shape=[jax.ShapeDtypeStruct((n, width), jnp.int32)] * 3,
+        name="bitonic_merge",
         interpret=interpret,
-    )(a_hi, a_lo, b_hi, b_lo, pa, pb)
+    )(hi, lo, payload)
 
 
 def merge_path_partition(keys_a: np.ndarray, keys_b: np.ndarray, tile: int):

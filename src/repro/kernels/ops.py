@@ -1,92 +1,111 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""jit'd entry points for the store's device path and the serving kernels.
 
-``interpret`` defaults to True (this container is CPU-only; on TPU pass
-interpret=False and the same BlockSpecs drive real Mosaic lowering).
+The store calls three of them: :func:`bloom_probe_filter` (batched point
+reads), :func:`bloom_build_hashes` (filter builds) and
+:func:`merge_runs_tiled` (compaction).  Each pads its inputs to a bucketed
+shape (powers of two above a floor; the probe takes its keys in fixed
+chunks), so each entry compiles once per bucket of one size axis: the
+count grows with the log of the data size, not with the number of runs or
+batches.
+
+Pallas kernels run compiled on a TPU and in interpret mode on the CPU;
+:func:`interpret_mode` decides which from the default backend, and every
+wrapper asks it — no caller passes a flag.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from .bloom_probe import bloom_probe_pallas
+from .bloom_probe import bloom_probe as _bloom_probe
 from .bloom_probe import hash_pair as _kernel_hash_pair
 from .flash_attention import flash_attention_pallas
-from .merge_path import bitonic_merge_pallas, merge_path_partition
+from .merge_path import ROWS, bitonic_merge_pallas, merge_path_partition
 from .paged_attention import paged_attention_pallas
 
+PROBE_BATCH = 1 << 16       # keys per probe launch (the last chunk padded)
+PROBE_MIN_WORDS = 1 << 12   # smallest padded bitset (16 KiB)
+HASH_MIN_BATCH = 1024       # smallest padded hash batch
+_SIGN = np.uint32(1 << 31)
 
-def split_u64(keys) -> Tuple[jax.Array, jax.Array]:
-    """u64 -> (lo32, hi32). Done in numpy: jax's default x32 mode would
-    silently truncate uint64."""
+
+def interpret_mode() -> bool:
+    """True when Pallas kernels must be interpreted (CPU backend), False when
+    they compile (TPU).  Any other backend has no path and is an error."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"no device path for platform {platform!r}: the "
+                       f"kernels run compiled on tpu or interpreted on cpu")
+
+
+def _bucket(n: int, floor: int) -> int:
+    """Smallest power of two >= max(n, floor)."""
+    return max(floor, 1 << max(0, int(n) - 1).bit_length())
+
+
+def split_u64(keys) -> Tuple[np.ndarray, np.ndarray]:
+    """u64 -> (lo32, hi32) host arrays.  Done in numpy: jax's default x32
+    mode would silently truncate uint64."""
     keys = np.asarray(keys, dtype=np.uint64)
     lo = (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
     hi = (keys >> np.uint64(32)).astype(np.uint32)
-    return jnp.asarray(lo), jnp.asarray(hi)
+    return lo, hi
 
 
-@partial(jax.jit, static_argnames=("k_hashes", "interpret"))
-def _bloom_probe_jit(lo, hi, bits, k_hashes, interpret):
-    return bloom_probe_pallas(lo, hi, bits, k_hashes, interpret=interpret)
+def _pad(x: np.ndarray, n: int) -> np.ndarray:
+    return x if x.size == n else np.concatenate(
+        [x, np.zeros(n - x.size, x.dtype)])
 
 
-def bloom_probe(keys, bits: jax.Array, k_hashes: int = 7,
-                interpret: bool = True) -> jax.Array:
-    """Probe u64 keys against a u32-word bitset. Returns bool 'maybe'."""
-    lo, hi = split_u64(keys)
-    return _bloom_probe_jit(lo, hi, bits, k_hashes, interpret)
+_probe_jit = jax.jit(_bloom_probe)
 
 
-def bloom_probe_filter(bf, keys, interpret: bool = True) -> np.ndarray:
-    """Probe a ``repro.core.bloom.BloomFilter`` with the Pallas kernel.
+def bloom_probe_filter(bf, keys) -> np.ndarray:
+    """Probe a ``repro.core.bloom.BloomFilter`` on the device.
 
-    The filter builds its bitset with the kernel's own 32-bit hash family, so
-    this returns bit-identical answers to ``bf.may_contain`` — it is the
-    engine's accelerator route for batched point reads (DESIGN.md §3).  Pads
-    the query batch up to the kernel's block multiple and strips the pad.
+    The filter builds its bitset with the device's own 32-bit hash family,
+    so this returns bit-identical answers to ``bf.may_contain`` — it is the
+    engine's device route for batched point reads (DESIGN.md §3).  The
+    bitset is padded to a bucketed length (the pad words are never indexed:
+    positions stay below ``bf.m_bits``) and uploaded once; the keys go in
+    chunks of ``PROBE_BATCH``, the last one padded, so the only shape that
+    varies between compiles is the bitset's bucket.  All chunks are launched
+    before any result is read back.
     """
-    from .bloom_probe import QUERY_BLOCK
-
     keys = np.asarray(keys, dtype=np.uint64)
     n = keys.size
     if bf.k == 0 or n == 0:
         return np.ones(n, dtype=bool)
-    # Quantize the batch shape (pow2 up to a block, then block multiples) so
-    # the jit cache holds a handful of kernels instead of one per batch size.
-    if n < QUERY_BLOCK:
-        m = 64
-        while m < n:
-            m *= 2
-    else:
-        m = -(-n // QUERY_BLOCK) * QUERY_BLOCK
-    if m != n:
-        keys = np.concatenate([keys, np.zeros(m - n, np.uint64)])
-    out = np.asarray(bloom_probe(keys, jnp.asarray(bf.bits), bf.k,
-                                 interpret=interpret))
-    return out[:n]
+    bits = jax.device_put(_pad(bf.bits, _bucket(bf.bits.size,
+                                                 PROBE_MIN_WORDS)))
+    m_bits, k = np.uint32(bf.m_bits), np.int32(bf.k)
+    outs = [_probe_jit(*split_u64(_pad(keys[i:i + PROBE_BATCH], PROBE_BATCH)),
+                       bits, m_bits, k) for i in range(0, n, PROBE_BATCH)]
+    return np.concatenate([np.asarray(o) for o in outs])[:n]
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _merge_tiles_jit(a_hi, a_lo, b_hi, b_lo, pa, pb, interpret=True):
-    return bitonic_merge_pallas(a_hi, a_lo, b_hi, b_lo, pa, pb,
-                                interpret=interpret)
+_hash_jit = jax.jit(_kernel_hash_pair)
 
 
-def merge_sorted_tiles(a: jax.Array, b: jax.Array, pa: jax.Array,
-                       pb: jax.Array, interpret: bool = True):
-    """Merge batches of sorted u32 tiles: (n,T)+(n,T) -> (n,2T) sorted.
+def bloom_build_hashes(keys) -> Tuple[np.ndarray, np.ndarray]:
+    """Device-side hash pass for filter *construction* (DESIGN.md §10).
 
-    Thin single-lane wrapper over the lexicographic (hi, lo) kernel with
-    hi = 0; u64 callers go through :func:`merge_runs_tiled`, which splits
-    keys into both lanes.
+    The ``use_pallas_bloom`` build route: compaction's output-filter rebuild
+    hashes every surviving key through the device's u32 hash family, and
+    ``core.bloom.build_bits`` packs the bitset from the returned pair —
+    bit-identical to ``core.bloom.hash_pair`` (the numpy twin), so probes
+    from either backend agree on the result.
     """
-    zero = jnp.zeros_like(a)
-    _, lo, payload = _merge_tiles_jit(zero, a, jnp.zeros_like(b), b, pa, pb,
-                                      interpret=interpret)
-    return lo, payload
+    keys = np.asarray(keys, dtype=np.uint64)
+    n = keys.size
+    lo, hi = split_u64(_pad(keys, _bucket(n, HASH_MIN_BATCH)))
+    h1, h2 = _hash_jit(lo, hi)
+    return np.asarray(h1)[:n], np.asarray(h2)[:n]
 
 
 def _to_u64_order(keys: np.ndarray) -> np.ndarray:
@@ -116,104 +135,92 @@ def _from_u64_order(merged: np.ndarray, dtype: np.dtype) -> np.ndarray:
     return (merged ^ np.uint64(1 << 63)).view(np.int64).astype(dtype)
 
 
-def _split_key_lanes(keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """order-mapped u64 -> (hi32, lo32) kernel lanes."""
-    return ((keys >> np.uint64(32)).astype(np.uint32),
-            (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+def _signed_lane(x: np.ndarray) -> np.ndarray:
+    """u32 -> int32 with the sign bit flipped: signed order = unsigned order."""
+    return (x ^ _SIGN).view(np.int32)
+
+
+def _unsigned_lane(x: np.ndarray) -> np.ndarray:
+    return x.view(np.uint32) ^ _SIGN
+
+
+_merge_jit = jax.jit(bitonic_merge_pallas, static_argnames=("interpret",))
 
 
 def merge_runs_tiled(keys_a: np.ndarray, keys_b: np.ndarray,
-                     tile: int = 256, interpret: bool = True
-                     ) -> Tuple[np.ndarray, np.ndarray]:
+                     tile: int = 256) -> Tuple[np.ndarray, np.ndarray]:
     """Full two-run merge: host-side merge-path partition + one bitonic
-    kernel launch per tile pair (the engine's ``use_pallas_merge`` lane).
+    kernel launch over every tile pair (the engine's ``use_pallas_merge``
+    lane).
 
     The partition and the tile packing are fully vectorized
     (``merge_path_partition`` + two scatter passes — no per-tile Python
-    loop); keys are carried as (hi, lo) u32 lanes so uint64 engine keys
-    merge exactly.  Returns (merged_keys, source_index) where source_index
-    is uint32 with bit 31 flagging entries from ``keys_b`` and the low bits
-    giving the source row, so the engine can permute value rows.  Tile pads
-    carry the lane maxima plus payload 0xFFFFFFFF, which the kernel's
-    payload tie-break orders after any real entry — keys equal to the dtype
-    maximum therefore merge correctly (runs longer than 2^31 - 1 entries
-    would collide with the pad payload, far beyond this engine's scale).
+    loop).  Each tile pair is packed as one row concat(A, reverse(B)), and
+    the row count is padded to a power of two (at least ``ROWS``).  Keys are
+    carried as (hi, lo) u32 lanes so uint64 engine keys merge exactly.
+    Returns (merged_keys, source_index) where source_index is uint32 with
+    bit 31 flagging entries from ``keys_b`` and the low bits giving the
+    source row, so the engine can permute value rows.  Tile pads carry the
+    lane maxima plus payload 0xFFFFFFFF, which the kernel's payload
+    tie-break orders after any real entry — keys equal to the dtype maximum
+    therefore merge correctly (runs longer than 2^31 - 1 entries would
+    collide with the pad payload, far beyond this engine's scale).
+    ``tile`` must be a power of two of at least 64.
     """
     out_dtype = keys_a.dtype
     keys_a = _to_u64_order(np.ascontiguousarray(keys_a))
     keys_b = _to_u64_order(np.ascontiguousarray(keys_b))
-    na, nb = len(keys_a), len(keys_b)
-    n_out = na + nb
     # Diagonal spacing = tile: merge-path guarantees each cell consumes at
     # most `tile` from either input; pads sort to the back (lane maxima), so
     # each cell's first `consumed` outputs are exact.
     bounds_a, bounds_b = merge_path_partition(keys_a, keys_b, tile)
     n_tiles = len(bounds_a) - 1
-    lanes = []
-    for keys, bounds, flag in ((keys_a, bounds_a, 0),
-                               (keys_b, bounds_b, np.uint32(1 << 31))):
+    n_rows = _bucket(n_tiles, ROWS)
+    width = 2 * tile
+    # pad payload 0xFFFFFFFF: sorts after every real source index, so the
+    # payload tie-break keeps pads strictly behind real entries even when a
+    # real key equals the dtype maximum
+    rows = [np.full((n_rows, width), 0xFFFFFFFF, dtype=np.uint32)
+            for _ in range(3)]
+    for keys, bounds, flag in ((keys_a, bounds_a, 0), (keys_b, bounds_b, _SIGN)):
         n = len(keys)
-        hi, lo = _split_key_lanes(keys)
-        t_hi = np.full((n_tiles, tile), 0xFFFFFFFF, dtype=np.uint32)
-        t_lo = np.full((n_tiles, tile), 0xFFFFFFFF, dtype=np.uint32)
-        # pad payload 0xFFFFFFFF: sorts after every real source index, so
-        # the kernel's payload tie-break keeps pads strictly behind real
-        # entries even when a real key equals the dtype maximum
-        t_p = np.full((n_tiles, tile), 0xFFFFFFFF, dtype=np.uint32)
-        if n:
-            idx = np.arange(n, dtype=np.int64)
-            t_of = np.searchsorted(bounds, idx, side="right") - 1
-            off = idx - bounds[t_of]
-            t_hi[t_of, off] = hi
-            t_lo[t_of, off] = lo
-            t_p[t_of, off] = idx.astype(np.uint32) | flag
-        lanes.extend((t_hi, t_lo, t_p))
-    a_hi, a_lo, pa, b_hi, b_lo, pb = lanes
-    ohi, olo, op = _merge_tiles_jit(
-        jnp.asarray(a_hi), jnp.asarray(a_lo), jnp.asarray(b_hi),
-        jnp.asarray(b_lo), jnp.asarray(pa), jnp.asarray(pb),
-        interpret=interpret)
-    ohi = np.asarray(ohi).reshape(-1)
-    olo = np.asarray(olo).reshape(-1)
-    op = np.asarray(op).reshape(-1)
+        if n == 0:
+            continue
+        idx = np.arange(n, dtype=np.int64)
+        t_of = np.searchsorted(bounds, idx, side="right") - 1
+        off = idx - bounds[t_of]
+        if flag:                      # B fills its half of the row reversed
+            off = width - 1 - off
+        rows[0][t_of, off] = (keys >> np.uint64(32)).astype(np.uint32)
+        rows[1][t_of, off] = (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+        rows[2][t_of, off] = idx.astype(np.uint32) | flag
+    ohi, olo, op = (np.asarray(r)[:n_tiles] for r in _merge_jit(
+        *(_signed_lane(r) for r in rows), interpret=interpret_mode()))
     # strip padding: valid entries per cell sit at the front
     cnt = np.diff(bounds_a) + np.diff(bounds_b)
-    keep = (np.arange(2 * tile)[None, :] < cnt[:, None]).ravel()
-    merged = (ohi.astype(np.uint64) << np.uint64(32)) | olo
-    return _from_u64_order(merged[keep], out_dtype), op[keep]
+    keep = np.arange(width)[None, :] < cnt[:, None]
+    merged = (_unsigned_lane(ohi[keep]).astype(np.uint64) << np.uint64(32)) \
+        | _unsigned_lane(olo[keep])
+    return _from_u64_order(merged, out_dtype), _unsigned_lane(op[keep])
 
 
-@jax.jit
-def _bloom_hash_jit(lo, hi):
-    return _kernel_hash_pair(lo, hi)
+_paged_attention_jit = jax.jit(paged_attention_pallas,
+                               static_argnames=("interpret",))
 
 
-def bloom_build_hashes(keys) -> Tuple[np.ndarray, np.ndarray]:
-    """Device-side hash pass for filter *construction* (DESIGN.md §10).
-
-    The ``use_pallas_bloom`` build route: compaction's output-filter rebuild
-    hashes every surviving key through the kernel's own u32 hash family on
-    the accelerator, and ``core.bloom.build_bits`` packs the bitset from the
-    returned pair — bit-identical to ``core.bloom.hash_pair`` (the numpy
-    twin), so probes from either backend agree on the result.
-    """
-    lo, hi = split_u64(keys)
-    h1, h2 = _bloom_hash_jit(lo, hi)
-    return np.asarray(h1), np.asarray(h2)
-
-
-@partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_tables: jax.Array, lengths: jax.Array,
-                    interpret: bool = True) -> jax.Array:
-    return paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
-                                  interpret=interpret)
+                    block_tables: jax.Array, lengths: jax.Array) -> jax.Array:
+    return _paged_attention_jit(q, k_pages, v_pages, block_tables, lengths,
+                                interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
-                                   "interpret"))
+_flash_attention_jit = jax.jit(
+    flash_attention_pallas,
+    static_argnames=("causal", "window", "bq", "bk", "interpret"))
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0, bq: int = 128,
-                    bk: int = 128, interpret: bool = True) -> jax.Array:
-    return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  bq=bq, bk=bk, interpret=interpret)
+                    bk: int = 128) -> jax.Array:
+    return _flash_attention_jit(q, k, v, causal=causal, window=window,
+                                bq=bq, bk=bk, interpret=interpret_mode())

@@ -64,8 +64,8 @@ def paged_attention_kernel(block_tables_ref, lengths_ref,   # scalar prefetch
 
 def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_tables: jax.Array,
-                           lengths: jax.Array,
-                           interpret: bool = True) -> jax.Array:
+                           lengths: jax.Array, *,
+                           interpret: bool) -> jax.Array:
     """q: (B,H,dh); k/v_pages: (n_phys_pages, page, KH, dh);
     block_tables: (B, pages_per_seq) int32; lengths: (B,) int32.
     Returns (B,H,dh)."""

@@ -1,49 +1,12 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose reference)."""
+"""Pure-jnp oracles for the attention kernels (the allclose reference).
+
+The store's device path has numpy references in ``repro.core``:
+``BloomFilter.may_contain`` for the probe and ``merge_runs_scalar`` for the
+merge."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-
-from .bloom_probe import hash_pair
-
-
-def bloom_probe_ref(keys_lo: jax.Array, keys_hi: jax.Array, bits: jax.Array,
-                    k_hashes: int) -> jax.Array:
-    h1, h2 = hash_pair(keys_lo, keys_hi)
-    m = jnp.uint32(bits.shape[0] * 32)
-    maybe = jnp.ones(keys_lo.shape, bool)
-    for i in range(k_hashes):
-        pos = (h1 + jnp.uint32(i) * h2) % m
-        word = bits[(pos >> jnp.uint32(5)).astype(jnp.int32)]
-        maybe &= ((word >> (pos & jnp.uint32(31))) & jnp.uint32(1)) != 0
-    return maybe
-
-
-def bloom_build_ref(keys_lo: np.ndarray, keys_hi: np.ndarray, m_words: int,
-                    k_hashes: int) -> np.ndarray:
-    """Host-side filter construction matching the kernel's hash family."""
-    h1, h2 = jax.device_get(hash_pair(jnp.asarray(keys_lo),
-                                      jnp.asarray(keys_hi)))
-    bits = np.zeros(m_words, dtype=np.uint32)
-    m = np.uint32(m_words * 32)
-    for i in range(k_hashes):
-        pos = (h1 + np.uint32(i) * h2) % m
-        np.bitwise_or.at(bits, (pos >> np.uint32(5)).astype(np.int64),
-                         np.uint32(1) << (pos & np.uint32(31)))
-    return bits
-
-
-def bitonic_merge_ref(a: jax.Array, b: jax.Array, pa: jax.Array,
-                      pb: jax.Array):
-    """Sorted merge of per-row tile pairs via argsort (stable order of equal
-    keys may differ from the network; tests compare keys exactly and check
-    payload/key pairing consistency)."""
-    keys = jnp.concatenate([a, b], axis=-1)
-    pay = jnp.concatenate([pa, pb], axis=-1)
-    order = jnp.argsort(keys, axis=-1)
-    return (jnp.take_along_axis(keys, order, -1),
-            jnp.take_along_axis(pay, order, -1))
 
 
 def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this produces a JSON artifact under benchmarks/artifacts/ with:
@@ -20,6 +17,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -31,6 +29,11 @@ from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell
 from repro.models.params import count_params
+
+# 512 placeholder host devices for the production meshes.  Set by the entry
+# points (``main`` here and in benchmarks/hillclimb.py) before the first
+# device query — jax reads it once, when its backend starts.
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
 
 ARTIFACT_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts"
 
@@ -147,6 +150,7 @@ def _write(path: Path, obj: dict):
 
 
 def main():
+    os.environ["XLA_FLAGS"] = HOST_DEVICES_FLAG
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=ARCH_IDS + [None])
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

@@ -35,7 +35,8 @@ def test_hlo_cost_model_counts_scan_trips():
         import jax, jax.numpy as jnp, json
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         def f(ws, x):
             def body(c, w):
                 return jnp.tanh(c @ w), ()
@@ -66,7 +67,8 @@ def test_mini_dryrun_cell_compiles_and_is_sharded():
         from repro.launch.specs import build_cell
         C.SHAPES["mini_train"] = ShapeSpec("mini_train", 64, 8, "train")
         C.SHAPES["mini_decode"] = ShapeSpec("mini_decode", 64, 8, "decode")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         report = {}
         for shape in ("mini_train", "mini_decode"):
             cell = build_cell("qwen3_4b", shape, mesh,

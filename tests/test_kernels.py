@@ -1,35 +1,82 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""Device kernels vs their references: shape/dtype sweeps (interpret mode
+on the CPU).  The probe is checked against ``BloomFilter.may_contain``, the
+merge against sorting and the engine's own merge."""
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import (bloom_probe, flash_attention, merge_runs_tiled,
-                           paged_attention)
+from repro.core.bloom import BloomFilter, build_bits, hash_pair
+from repro.kernels import (bloom_probe_filter, flash_attention,
+                           merge_runs_tiled, paged_attention)
 from repro.kernels import ops, ref
 
 
+def _filter(keys, m_words, k):
+    """A BloomFilter over ``keys`` with an explicit word and hash count."""
+    bf = BloomFilter(np.zeros(0, np.uint64), 0)
+    bf.m_bits, bf.k, bf.n_keys = m_words * 32, k, keys.size
+    bf.bits = build_bits(*hash_pair(keys), k, m_words * 32)
+    return bf
+
+
+@pytest.mark.parametrize("platform,interpret", [("cpu", True),
+                                                ("tpu", False),
+                                                ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, platform, interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="no device path"):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is interpret
+
+
 @pytest.mark.parametrize("n,m_words,k", [(512, 128, 5), (2048, 1024, 7),
-                                         (4096, 64, 3)])
+                                         (4096, 64, 3),
+                                         # queries span two probe chunks
+                                         (40000, 16384, 7)])
 def test_bloom_probe_sweep(n, m_words, k):
     rng = np.random.default_rng(n + k)
     keys = rng.integers(0, 2**63, n, dtype=np.uint64)
-    lo, hi = ops.split_u64(keys)
-    bits = ref.bloom_build_ref(np.asarray(lo), np.asarray(hi), m_words, k)
-    got = np.asarray(bloom_probe(keys, jnp.asarray(bits), k))
-    exp = np.asarray(ref.bloom_probe_ref(lo, hi, jnp.asarray(bits), k))
-    assert (got == exp).all()
-    assert got.all()  # no false negatives on members
+    bf = _filter(keys, m_words, k)
+    queries = np.concatenate([keys, rng.integers(0, 2**63, n, np.uint64)])
+    got = bloom_probe_filter(bf, queries)
+    assert (got == bf.may_contain(queries)).all()
+    assert got[:n].all()  # no false negatives on members
 
 
 def test_bloom_fpr_reasonable():
     rng = np.random.default_rng(9)
     keys = rng.integers(0, 2**62, 4096, dtype=np.uint64)
-    lo, hi = ops.split_u64(keys)
-    bits = ref.bloom_build_ref(np.asarray(lo), np.asarray(hi), 2048, 7)
+    bf = _filter(keys, 2048, 7)
     absent = rng.integers(2**62, 2**63, 8192, dtype=np.uint64)
-    fpr = float(np.mean(np.asarray(bloom_probe(absent, jnp.asarray(bits), 7))))
+    fpr = float(np.mean(bloom_probe_filter(bf, absent)))
     assert fpr < 0.05
+
+
+def test_device_path_compiles_once_per_bucket():
+    """Probe keys go in fixed chunks, and bitset, hash batch and tile counts
+    are padded to power-of-two buckets, so sizes that share a bucket share
+    one compile and answers stay exact."""
+    rng = np.random.default_rng(4)
+    keys = rng.integers(0, 2**63, 3000, dtype=np.uint64)
+    before = ops._probe_jit._cache_size()
+    for n_keys, n_q in ((3000, 1), (2900, 700), (2500, 1024)):
+        bf = BloomFilter(keys[:n_keys], 10)
+        q = rng.integers(0, 2**63, n_q, dtype=np.uint64)
+        assert (bloom_probe_filter(bf, q) == bf.may_contain(q)).all()
+    assert ops._probe_jit._cache_size() - before <= 1
+    h1, h2 = ops.bloom_build_hashes(keys[:5])
+    e1, e2 = hash_pair(keys[:5])
+    assert (h1 == e1).all() and (h2 == e2).all()
+    before = ops._merge_jit._cache_size()
+    for na, nb in ((100, 200), (300, 400), (5, 1000)):
+        a = np.sort(rng.integers(0, 2**63, na, dtype=np.uint64))
+        b = np.sort(rng.integers(0, 2**63, nb, dtype=np.uint64))
+        mk, _ = merge_runs_tiled(a, b)
+        assert (mk == np.sort(np.concatenate([a, b]))).all()
+    assert ops._merge_jit._cache_size() - before <= 1
 
 
 @pytest.mark.parametrize("na,nb,tile", [(777, 1333, 256), (1, 5000, 128),
