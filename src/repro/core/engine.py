@@ -24,11 +24,15 @@ Optional telemetry (DESIGN.md §14): ``LSMConfig.telemetry`` carries a
 every instrumentation site is a single attribute load + ``is None`` test;
 when set, public ops record per-op-class latency into per-thread histograms
 (no locks on the read path) and lifecycle paths (flush/compaction/stall/
-view-rebuild) emit trace events.
+view-rebuild) emit trace events.  Always on, whatever ``telemetry`` says:
+the ``IOStats`` timers of multi_get, the device probe, flush, compaction
+and scan, and the profiler spans (``lsm.*``) that ``telemetry.span`` opens
+at the same sites while a ``jax.profiler`` session records.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import threading
 import time
@@ -45,7 +49,7 @@ from .memtable import ImmutableMemtable, Memtable, WriteAheadLog
 from .policy import CompactionTask, MergePolicy, make_policy
 from .run import SortedRun, build_run, merge_runs
 from .scheduler import CompactJob, CompactionScheduler, FlushJob
-from .telemetry import Telemetry
+from .telemetry import Telemetry, span, tracing
 from .tuner import OnlineTuner, TunerStep
 from .types import (BLOCK_SIZE, KEY_BYTES, KEY_DTYPE, SEQ_DTYPE,
                     TOMBSTONE_LEN, IOStats, StatsHub)
@@ -223,6 +227,9 @@ class LSMStore:
         # triplet between worker installs and snapshot releases.
         self._imm: List[ImmutableMemtable] = []
         self._maint_lock = threading.Lock()
+        # Ids of flushed memtables: the spans of a rotation, its flush and
+        # the compactions that flush causes carry the same one.
+        self._memtable_ids = itertools.count(1)
         # Online tuning (DESIGN.md §17).  The tuner is cached on the store
         # so the per-write check is one attribute + `is None` test (the
         # telemetry zero-overhead contract); bind() makes this store the
@@ -296,12 +303,13 @@ class LSMStore:
         if f is not None:
             f.check("wal_fsync")
         tel = self.config.telemetry
-        if tel is None:
+        with span("lsm.wal_fsync"):
+            if tel is None:
+                self.wal.fsync(st)
+                return
+            t0 = time.perf_counter_ns()
             self.wal.fsync(st)
-            return
-        t0 = time.perf_counter_ns()
-        self.wal.fsync(st)
-        tel.record("wal_fsync", time.perf_counter_ns() - t0)
+            tel.record("wal_fsync", time.perf_counter_ns() - t0)
 
     def configure_cache(self, cache_bytes: int, pin_l0_bytes: int = 0,
                         policy: Optional[str] = None) -> None:
@@ -376,13 +384,24 @@ class LSMStore:
                                    # leaves no partial record anywhere
         st = self._stats.local()
         self._seq += 1
+        if tracing():       # one check per write: no span object when off
+            with span("lsm.wal_append", bytes=len(value or b"")):
+                self._log(key, value, st)
+            with span("lsm.memtable_put"):
+                self.memtable.put(int(key), self._seq, value)
+        else:
+            self._log(key, value, st)
+            self.memtable.put(int(key), self._seq, value)
+        if self.memtable.is_full():
+            self._on_memtable_full()
+
+    def _log(self, key: int, value: Optional[bytes], st: IOStats) -> None:
+        """Append one write to the active WAL under the current seq (and
+        fsync it when every write must be durable)."""
         self.wal.append(1 if value is None else 0, key, self._seq,
                         value or b"", st)
         if self.config.wal_fsync_every_write:
             self._wal_fsync(st)
-        self.memtable.put(int(key), self._seq, value)
-        if self.memtable.is_full():
-            self._on_memtable_full()
 
     # ------------------------------------------------------- batched writes
     def put_batch(self, keys, values) -> None:
@@ -514,33 +533,46 @@ class LSMStore:
             st.write_stalls += 1
             self._compact_until_quiet()
         tel = self.config.telemetry
-        t0 = tok = 0
+        mid = next(self._memtable_ids)
+        entries = len(self.memtable)
+        tok = tel.emit("flush_start", entries=entries) \
+            if tel is not None else 0
+        t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
+        with span("lsm.flush", memtable=mid, entries=entries):
+            self._wal_fsync(st)
+            f = self.config.faults
+            if f is not None:
+                f.check("flush_write")
+            run = self._build_l0(self.memtable, st)
+            # The WAL/memtable are released only *after* the manifest fsync
+            # in _commit(): if that fsync fails, the flushed records are
+            # still in the (fsynced) WAL and crash()+recover() replays them
+            # — releasing first would turn a manifest fault into silent
+            # data loss.
+            self.memtable.clear()
+            self.wal.truncate()
+        dur = time.perf_counter_ns() - t0
+        st.flush_ns += dur
+        st.flush_cpu_ns += time.thread_time_ns() - c0
         if tel is not None:
-            t0 = time.perf_counter_ns()
-            tok = tel.emit("flush_start", entries=len(self.memtable))
-        self._wal_fsync(st)
-        f = self.config.faults
-        if f is not None:
-            f.check("flush_write")
-        run = self.memtable.to_run(self._bits_for_level(0), st,
-                                   hash_fn=self._bloom_hash_fn())
-        if len(run):
-            levels = [list(lvl) for lvl in self._levels]
-            levels[0].append(run)  # newest last
-            self._levels = levels  # atomic swap: readers never see a torn L0
-            self._commit()
-        # The WAL/memtable are released only *after* the manifest fsync in
-        # _commit(): if that fsync fails, the flushed records are still in
-        # the (fsynced) WAL and crash()+recover() replays them — releasing
-        # first would turn a manifest fault into silent data loss.
-        self.memtable.clear()
-        self.wal.truncate()
-        if tel is not None:
-            dur = time.perf_counter_ns() - t0
             tel.record("flush", dur)
             tel.emit("flush_end", token=tok, entries=len(run),
                      t0=t0, dur_ns=dur)
-        self._compact_until_quiet()
+        self._compact_until_quiet(cause=mid)
+
+    def _build_l0(self, memtable: Memtable, st: IOStats) -> SortedRun:
+        """The flush body both paths share: freeze ``memtable`` into a run
+        and install it as the newest L0 run (an atomic list swap: readers
+        never see a torn L0)."""
+        with span("lsm.run_build", entries=len(memtable)):
+            run = memtable.to_run(self._bits_for_level(0), st,
+                                  hash_fn=self._bloom_hash_fn())
+        if len(run):
+            levels = [list(lvl) for lvl in self._levels]
+            levels[0].append(run)  # newest last
+            self._levels = levels
+            self._commit()
+        return run
 
     # ------------------------------------------------- async rotation path
     def _rotate(self):
@@ -555,9 +587,12 @@ class LSMStore:
         """
         if len(self.memtable) == 0:
             return
-        self._throttle()
-        self._wal_fsync(self._stats.local())
-        imm = ImmutableMemtable(self.memtable, self.wal)
+        mid = next(self._memtable_ids)
+        depth = len(self._imm) + len(self._levels[0])
+        with span("lsm.rotate", memtable=mid, depth=depth):
+            self._throttle(depth)
+            self._wal_fsync(self._stats.local())
+            imm = ImmutableMemtable(self.memtable, self.wal, mid)
         with self._scheduler.lock:
             self._imm = self._imm + [imm]   # copy-on-write: readers hold refs
         self.memtable = Memtable(self.config.memtable_bytes,
@@ -585,10 +620,10 @@ class LSMStore:
                 raise
             self._enter_degraded(exc.__cause__)
 
-    def _throttle(self):
+    def _throttle(self, depth: int):
         """LevelDB-style write-pressure control at rotation points.
 
-        Pressure = queued L0 runs + immutable memtables.  At
+        Pressure (``depth``) = queued L0 runs + immutable memtables.  At
         ``slowdown_trigger`` each rotation yields its CPU slice to the
         workers (see ``_SLOWDOWN_SLEEP_S``); at ``stall_trigger`` the
         rotation blocks until the scheduler drains below the trigger (or
@@ -599,7 +634,6 @@ class LSMStore:
         cfg = self.config
         st = self._stats.local()
         tel = cfg.telemetry
-        depth = len(self._imm) + len(self._levels[0])
         t0 = time.perf_counter_ns()
         if cfg.stall_trigger > 0 and depth >= cfg.stall_trigger:
             st.write_stalls += 1
@@ -610,10 +644,11 @@ class LSMStore:
             # steady state (e.g. L0 legitimately holds l0_trigger-1 runs)
             # and waiting longer would deadlock the writer.
             sched = self._scheduler
-            sched.wait_until(
-                lambda: sched.idle()
-                or (len(self._imm) + len(self._levels[0]))
-                < cfg.stall_trigger)
+            with span("lsm.throttle", kind="stall", depth=depth):
+                sched.wait_until(
+                    lambda: sched.idle()
+                    or (len(self._imm) + len(self._levels[0]))
+                    < cfg.stall_trigger)
             dt = time.perf_counter_ns() - t0
             if tel is not None:
                 tel.record("stall", dt)
@@ -621,7 +656,8 @@ class LSMStore:
                          t0=t0, dur_ns=dt)
         elif cfg.slowdown_trigger > 0 and depth >= cfg.slowdown_trigger:
             st.write_slowdowns += 1
-            time.sleep(_SLOWDOWN_SLEEP_S)
+            with span("lsm.throttle", kind="slowdown", depth=depth):
+                time.sleep(_SLOWDOWN_SLEEP_S)
             dt = time.perf_counter_ns() - t0
             if tel is not None:
                 tel.record("stall", dt)
@@ -900,41 +936,40 @@ class LSMStore:
         st = self._stats.local()
         if len(self._levels[0]) >= self.config.l0_stop_writes_trigger:
             st.write_stalls += 1
-            self._compact_until_quiet()
+            self._compact_until_quiet(cause=imm.mid)
         if sched.aborting:
             return None     # crash in progress: imm stays queued for replay
         f = self.config.faults
         if f is not None:
             f.check("flush_write")
         tel = self.config.telemetry
-        t0 = tok = 0
-        if tel is not None:
-            t0 = time.perf_counter_ns()
-            tok = tel.emit("flush_start", entries=len(imm.memtable), bg=1)
-        run = imm.memtable.to_run(self._bits_for_level(0), st,
-                                  hash_fn=self._bloom_hash_fn())
-        if len(run):
-            levels = [list(lvl) for lvl in self._levels]
-            levels[0].append(run)  # newest last
-            self._levels = levels
-            self._commit()
-        # Only now drop the readable immutable memtable: between install and
-        # pop a reader may see the entries twice (same seq, same value) but
-        # never zero times.  The WAL segment retires with it — the data is
-        # durable in the manifest as of _commit's fsync.
-        with sched.lock:
-            self._imm = [m for m in self._imm if m is not imm]
-            sched.lock.notify_all()     # wake write-pressure waiters
+        entries = len(imm.memtable)
+        tok = tel.emit("flush_start", entries=entries, bg=1) \
+            if tel is not None else 0
+        t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
+        with span("lsm.flush", memtable=imm.mid, entries=entries):
+            run = self._build_l0(imm.memtable, st)
+            # Only now drop the readable immutable memtable: between install
+            # and pop a reader may see the entries twice (same seq, same
+            # value) but never zero times.  The WAL segment retires with it
+            # — the data is durable in the manifest as of _commit's fsync.
+            with sched.lock:
+                self._imm = [m for m in self._imm if m is not imm]
+                sched.lock.notify_all()     # wake write-pressure waiters
+        dur = time.perf_counter_ns() - t0
         st.bg_flushes += 1
+        st.flush_queue_ns += t0 - imm.rotated_ns
+        st.flush_ns += dur
+        st.flush_cpu_ns += time.thread_time_ns() - c0
         if tel is not None:
-            dur = time.perf_counter_ns() - t0
             tel.record("flush", dur)
             tel.emit("flush_end", token=tok, entries=len(run), bg=1,
                      t0=t0, dur_ns=dur)
-        return CompactJob()
+        return CompactJob(cause=imm.mid)
 
-    def _bg_compact_one(self) -> Optional[CompactionTask]:
-        """Plan + apply one compaction task (worker thread).
+    def _bg_compact_one(self, cause: int) -> Optional[CompactionTask]:
+        """Plan + apply one compaction task (worker thread); ``cause`` is
+        the id of the memtable whose flush queued it.
 
         The input version is pinned for the duration of the merge — exactly
         the retention ``_commit``'s cache-invalidation protocol assumes —
@@ -947,7 +982,7 @@ class LSMStore:
         pinned = self.manifest.pin_current()
         try:
             task = self._plan_one()
-            if task is None or not self._apply(task):
+            if task is None or not self._apply(task, cause):
                 return None
             self._stats.local().bg_compactions += 1
             return task
@@ -982,17 +1017,19 @@ class LSMStore:
         return dataclasses.replace(
             task, src_run_ids=tuple(r.run_id for r in srcs))
 
-    def _compact_until_quiet(self):
+    def _compact_until_quiet(self, cause: int = 0):
         while True:
             if self._scheduler is not None and self._scheduler.aborting:
                 return      # crash in progress: bail at the task boundary
             task = self._plan_one()
             if task is None:
                 return
-            self._apply(task)
+            self._apply(task, cause)
 
-    def _apply(self, task: CompactionTask) -> bool:
+    def _apply(self, task: CompactionTask, cause: int = 0) -> bool:
         """Merge the task's inputs and install the result as a new version.
+        ``cause``: the id of the memtable whose flush led to this task (0
+        for maintenance), carried by the ``lsm.compaction`` span.
 
         The merged level lists are built copy-on-write and published with
         one reference assignment, so concurrent readers either see the old
@@ -1009,32 +1046,36 @@ class LSMStore:
         dsts = levels[task.dst_level] if task.include_dst else []
         st = self._stats.local()
         tel = self.config.telemetry
-        t0 = tok = 0
+        tok = tel.emit("compaction_start", src=task.src_level,
+                       dst=task.dst_level, runs=len(srcs) + len(dsts)) \
+            if tel is not None else 0
+        t0, c0 = time.perf_counter_ns(), time.thread_time_ns()
+        with span("lsm.compaction", src=task.src_level, dst=task.dst_level,
+                  entries=sum(len(r) for r in srcs + dsts), cause=cause):
+            deepest = self._deepest_nonempty()
+            drop_tombs = task.include_dst and task.dst_level >= deepest
+            f = self.config.faults
+            if f is not None:
+                f.check("compaction_merge")
+            merged = merge_runs(srcs + dsts,
+                                self._bits_for_level(task.dst_level),
+                                st, drop_tombstones=drop_tombs,
+                                block_size=self.config.block_size,
+                                key_bytes=self.config.key_bytes,
+                                pair_merge=self._pair_merge_fn(),
+                                bloom_hash=self._bloom_hash_fn())
+            levels[task.src_level] = []
+            if task.include_dst:
+                levels[task.dst_level] = [merged] if len(merged) else []
+            elif len(merged):
+                levels[task.dst_level].append(merged)
+            self._levels = levels
+            self._max_level = max(self._max_level, task.dst_level)
+            self._commit()
+        dur = time.perf_counter_ns() - t0
+        st.compaction_ns += dur
+        st.compaction_cpu_ns += time.thread_time_ns() - c0
         if tel is not None:
-            t0 = time.perf_counter_ns()
-            tok = tel.emit("compaction_start", src=task.src_level,
-                           dst=task.dst_level, runs=len(srcs) + len(dsts))
-        deepest = self._deepest_nonempty()
-        drop_tombs = task.include_dst and task.dst_level >= deepest
-        f = self.config.faults
-        if f is not None:
-            f.check("compaction_merge")
-        merged = merge_runs(srcs + dsts, self._bits_for_level(task.dst_level),
-                            st, drop_tombstones=drop_tombs,
-                            block_size=self.config.block_size,
-                            key_bytes=self.config.key_bytes,
-                            pair_merge=self._pair_merge_fn(),
-                            bloom_hash=self._bloom_hash_fn())
-        levels[task.src_level] = []
-        if task.include_dst:
-            levels[task.dst_level] = [merged] if len(merged) else []
-        elif len(merged):
-            levels[task.dst_level].append(merged)
-        self._levels = levels
-        self._max_level = max(self._max_level, task.dst_level)
-        self._commit()
-        if tel is not None:
-            dur = time.perf_counter_ns() - t0
             tel.record("compaction", dur)
             tel.emit("compaction_end", token=tok, src=task.src_level,
                      dst=task.dst_level, entries=len(merged),
@@ -1050,28 +1091,30 @@ class LSMStore:
         return deepest
 
     def _commit(self):
-        st = self._stats.local()
-        self.manifest.commit(self._levels, self._max_level, self._seq, st)
-        f = self.config.faults
-        if f is not None:
-            # after the in-memory commit, before durability: the edit is
-            # appended but not synced — exactly the window a real fsync
-            # failure leaves behind
-            f.check("manifest_fsync")
-        self.manifest.fsync(st)
-        with self._maint_lock:
-            # The gc + retain + repin triplet must not interleave with a
-            # concurrent snapshot release (or another install): a retain
-            # computed from a stale id set could drop blocks the newer
-            # version just pinned.
-            self.manifest.gc()
-            if self.block_cache is not None:
-                # Invalidation protocol (DESIGN.md §9): drop blocks of runs
-                # that compaction retired (snapshot-pinned runs stay live in
-                # storage), then re-derive the DRAM-resident L0 from the new
-                # version.
-                self.block_cache.retain(self.storage.ids())
-                self.pinned_l0.repin(self._levels[0])
+        with span("lsm.commit"):
+            st = self._stats.local()
+            self.manifest.commit(self._levels, self._max_level, self._seq,
+                                 st)
+            f = self.config.faults
+            if f is not None:
+                # after the in-memory commit, before durability: the edit is
+                # appended but not synced — exactly the window a real fsync
+                # failure leaves behind
+                f.check("manifest_fsync")
+            self.manifest.fsync(st)
+            with self._maint_lock:
+                # The gc + retain + repin triplet must not interleave with a
+                # concurrent snapshot release (or another install): a retain
+                # computed from a stale id set could drop blocks the newer
+                # version just pinned.
+                self.manifest.gc()
+                if self.block_cache is not None:
+                    # Invalidation protocol (DESIGN.md §9): drop blocks of
+                    # runs that compaction retired (snapshot-pinned runs stay
+                    # live in storage), then re-derive the DRAM-resident L0
+                    # from the new version.
+                    self.block_cache.retain(self.storage.ids())
+                    self.pinned_l0.repin(self._levels[0])
 
     # -------------------------------------------------------------- bloom
     def _bits_for_level(self, level: int) -> float:
@@ -1230,13 +1273,41 @@ class LSMStore:
     # call, so toggling them on a live store takes effect.  A device path
     # that fails raises: there is no silent numpy fallback.
 
+    # Each route calls its entry by name on ``repro.kernels.ops`` at call
+    # time, so a wrapper installed on the module (a profiler's, a test's)
+    # sees every call, and charges the entry's wall time to the calling
+    # thread's IOStats.
+
     def _bloom_probe_fn(self):
         """The device batched-probe route (``kernels.ops.bloom_probe_filter``)
-        when ``use_pallas_bloom`` is on, else None (numpy probes)."""
+        when ``use_pallas_bloom`` is on, else None (numpy probes).  Each
+        call counts ``probe_calls`` and ``probe_ns``."""
         if not self.config.use_pallas_bloom:
             return None
-        from repro.kernels.ops import bloom_probe_filter
-        return bloom_probe_filter
+        from repro.kernels import ops
+        hub = self._stats
+
+        def probe(bf, keys):
+            t0 = time.perf_counter_ns()
+            out = ops.bloom_probe_filter(bf, keys)
+            st = hub.local()
+            st.probe_calls += 1
+            st.probe_ns += time.perf_counter_ns() - t0
+            return out
+        return probe
+
+    def _timed_entry(self, name: str):
+        """``kernels.ops.<name>`` with its wall time charged to
+        ``IOStats.entry_ns``."""
+        from repro.kernels import ops
+        hub = self._stats
+
+        def call(*args, **kwargs):
+            t0 = time.perf_counter_ns()
+            out = getattr(ops, name)(*args, **kwargs)
+            hub.local().entry_ns += time.perf_counter_ns() - t0
+            return out
+        return call
 
     def _bloom_hash_fn(self):
         """The device filter-*build* hash route, sharing the
@@ -1246,8 +1317,7 @@ class LSMStore:
         numpy family, so either backend may probe the result."""
         if not self.config.use_pallas_bloom:
             return None
-        from repro.kernels.ops import bloom_build_hashes
-        return bloom_build_hashes
+        return self._timed_entry("bloom_build_hashes")
 
     def _pair_merge_fn(self):
         """The device merge-path compaction lane when ``use_pallas_merge``
@@ -1257,8 +1327,7 @@ class LSMStore:
         bit-for-bit against the numpy ladder and ``merge_runs_scalar``."""
         if not self.config.use_pallas_merge:
             return None
-        from repro.kernels.ops import merge_runs_tiled
-        return merge_runs_tiled
+        return self._timed_entry("merge_runs_tiled")
 
     def multi_get(self, keys: Sequence[int],
                   snapshot: Optional[Version] = None) -> List[Optional[bytes]]:
@@ -1271,16 +1340,18 @@ class LSMStore:
         is identical to the equivalent sequence of scalar ``get`` calls.
         """
         tel = self.config.telemetry
-        if tel is None:
-            return self._multi_get_impl(keys, snapshot)
         t0 = time.perf_counter_ns()
         try:
             out = self._multi_get_impl(keys, snapshot)
         except CorruptionError as e:
-            tel.emit("corruption", run_id=e.run_id, block_id=e.block_id,
-                     where="multi_get")
+            if tel is not None:
+                tel.emit("corruption", run_id=e.run_id, block_id=e.block_id,
+                         where="multi_get")
             raise
-        tel.record("multi_get", time.perf_counter_ns() - t0)
+        dt = time.perf_counter_ns() - t0
+        self._stats.local().multi_get_ns += dt
+        if tel is not None:
+            tel.record("multi_get", dt)
         return out
 
     def _multi_get_impl(self, keys: Sequence[int],
@@ -1293,37 +1364,40 @@ class LSMStore:
         results: List[Optional[bytes]] = [None] * n
         if n == 0:
             return results
-        pending = np.arange(n, dtype=np.int64)
-        if snapshot is None:
-            for mt in self._mem_sources():
-                if len(mt) == 0 or pending.size == 0:
+        with span("lsm.multi_get", keys=n):
+            pending = np.arange(n, dtype=np.int64)
+            if snapshot is None:
+                with span("lsm.memtable_probe", keys=n):
+                    for mt in self._mem_sources():
+                        if len(mt) == 0 or pending.size == 0:
+                            continue
+                        keep = []
+                        for j in pending:
+                            hit = mt.get(int(keys_arr[j]))
+                            if hit is not None:
+                                # the value, or None for a tombstone
+                                results[int(j)] = hit[1]
+                            else:
+                                keep.append(int(j))
+                        pending = np.asarray(keep, dtype=np.int64)
+            cfg = self.config
+            use_bloom = cfg.bits_per_key > 0
+            paranoid = cfg.paranoid_checks
+            faults = cfg.faults
+            probe_fn = self._bloom_probe_fn()
+            for run in self._runs_newest_first(self._read_state(snapshot)):
+                if pending.size == 0:
+                    break
+                if len(run) == 0:
                     continue
-                keep = []
-                for j in pending:
-                    hit = mt.get(int(keys_arr[j]))
-                    if hit is not None:
-                        results[int(j)] = hit[1]   # value, or None: tombstone
-                    else:
-                        keep.append(int(j))
-                pending = np.asarray(keep, dtype=np.int64)
-        cfg = self.config
-        use_bloom = cfg.bits_per_key > 0
-        paranoid = cfg.paranoid_checks
-        faults = cfg.faults
-        probe_fn = self._bloom_probe_fn()
-        for run in self._runs_newest_first(self._read_state(snapshot)):
-            if pending.size == 0:
-                break
-            if len(run) == 0:
-                continue
-            st.runs_touched_point += int(pending.size)
-            found, values = run.point_get_batch(
-                keys_arr[pending], st, use_bloom, probe_fn,
-                cache=self.block_cache, paranoid=paranoid, faults=faults)
-            if found.any():
-                for p in np.nonzero(found)[0]:
-                    results[int(pending[p])] = values[int(p)]
-                pending = pending[~found]
+                st.runs_touched_point += int(pending.size)
+                found, values = run.point_get_batch(
+                    keys_arr[pending], st, use_bloom, probe_fn,
+                    cache=self.block_cache, paranoid=paranoid, faults=faults)
+                if found.any():
+                    for p in np.nonzero(found)[0]:
+                        results[int(pending[p])] = values[int(p)]
+                    pending = pending[~found]
         return results
 
     def seek(self, key: int, snapshot: Optional[Version] = None) -> Optional[int]:
@@ -1423,11 +1497,13 @@ class LSMStore:
         identical either way, only the cost differs.
         """
         tel = self.config.telemetry
-        if tel is None:
-            return self._scan_impl(start_key, count, snapshot)
         t0 = time.perf_counter_ns()
-        out = self._scan_impl(start_key, count, snapshot)
-        tel.record("scan", time.perf_counter_ns() - t0)
+        with span("lsm.scan", count=count):
+            out = self._scan_impl(start_key, count, snapshot)
+        dt = time.perf_counter_ns() - t0
+        self._stats.local().scan_ns += dt
+        if tel is not None:
+            tel.record("scan", dt)
         return out
 
     def _scan_impl(self, start_key: int, count: int,

@@ -35,6 +35,7 @@ cache; only misses charge ``blocks_read``.
 """
 from __future__ import annotations
 
+import time
 from bisect import bisect_right
 from typing import Iterator, List, Optional, Sequence, Tuple
 
@@ -42,6 +43,7 @@ import numpy as np
 
 from .memtable import Memtable
 from .run import SortedRun
+from .telemetry import span
 from .types import KEY_DTYPE, TOMBSTONE_LEN, IOStats
 
 _FIRST_DEMAND = 16
@@ -191,25 +193,30 @@ class MergingIterator:
                 return None
 
     def scan(self, start_key: int, count: int) -> List[Tuple[int, bytes]]:
-        """First ``count`` live entries with key >= start_key."""
-        self.seek(start_key, expected=count)
+        """First ``count`` live entries with key >= start_key.  The seek's
+        wall time is charged to ``stats.scan_seek_ns``."""
+        t0 = time.perf_counter_ns()
+        with span("lsm.scan_seek", runs=len(self._cursors)):
+            self.seek(start_key, expected=count)
+        self.stats.scan_seek_ns += time.perf_counter_ns() - t0
         out: List[Tuple[int, bytes]] = []
-        while len(out) < count:
-            i = self._bi
-            bk, bv = self._bk, self._bv
-            nb = len(bk)
-            if i >= nb:
-                if self._exhausted or not self._refill():
-                    break
-                continue
-            need = count - len(out)
-            while i < nb and need:
-                v = bv[i]
-                if v is not None:
-                    out.append((bk[i], v))
-                    need -= 1
-                i += 1
-            self._bi = i
+        with span("lsm.scan_merge", count=count):
+            while len(out) < count:
+                i = self._bi
+                bk, bv = self._bk, self._bv
+                nb = len(bk)
+                if i >= nb:
+                    if self._exhausted or not self._refill():
+                        break
+                    continue
+                need = count - len(out)
+                while i < nb and need:
+                    v = bv[i]
+                    if v is not None:
+                        out.append((bk[i], v))
+                        need -= 1
+                    i += 1
+                self._bi = i
         return out
 
     def __iter__(self) -> Iterator[Tuple[int, bytes]]:

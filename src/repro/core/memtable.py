@@ -9,6 +9,7 @@ durability/recovery logic is real and testable without a filesystem
 from __future__ import annotations
 
 import struct
+import time
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -411,11 +412,16 @@ class ImmutableMemtable:
     pair stays readable on every read path (between the active memtable and
     L0, newest-first) until the background flush installs the run, and the
     WAL segment — fully fsynced at rotation — is the durable twin replayed
-    by recovery if a crash beats the flush.
+    by recovery if a crash beats the flush.  ``mid`` names the memtable in
+    the spans of its rotation, flush and the compactions that flush causes;
+    ``rotated_ns`` (``time.perf_counter_ns``) starts its queue wait
+    (``IOStats.flush_queue_ns``).
     """
 
-    __slots__ = ("memtable", "wal")
+    __slots__ = ("memtable", "wal", "mid", "rotated_ns")
 
-    def __init__(self, memtable: Memtable, wal: WriteAheadLog):
+    def __init__(self, memtable: Memtable, wal: WriteAheadLog, mid: int):
         self.memtable = memtable.freeze()
         self.wal = wal
+        self.mid = mid
+        self.rotated_ns = time.perf_counter_ns()

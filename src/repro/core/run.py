@@ -22,6 +22,7 @@ import numpy as np
 
 from .bloom import BloomFilter
 from .faults import CorruptionError, crc32c_rows
+from .telemetry import span
 from .types import (BLOCK_SIZE, KEY_BYTES, KEY_DTYPE, SEQ_DTYPE,
                     TOMBSTONE_LEN, IOStats)
 
@@ -223,44 +224,51 @@ class SortedRun:
         values: List[Optional[bytes]] = [None] * n
         if len(self) == 0:
             return found, values  # no blocks to read
+        run = self.run_id
         if use_bloom and self.bloom.k > 0:
             stats.bloom_probes += n
-            if probe_fn is not None:
-                maybe = np.asarray(probe_fn(self.bloom, keys), dtype=bool)
-            else:
-                maybe = self.bloom.may_contain(keys)
+            with span("lsm.filter_probe", run=run, keys=n):
+                if probe_fn is not None:
+                    maybe = np.asarray(probe_fn(self.bloom, keys),
+                                       dtype=bool)
+                else:
+                    maybe = self.bloom.may_contain(keys)
             stats.bloom_negatives += int(n - np.count_nonzero(maybe))
             cand = np.nonzero(maybe)[0]
         else:
             cand = np.arange(n)
-        if cand.size == 0:
+        m = int(cand.size)
+        if m == 0:
             return found, values
         # Fence pointers give each candidate its unique block: 1 read apiece.
-        idx = np.searchsorted(self.keys, keys[cand])
-        blocks = self.block_of[np.minimum(idx, len(self) - 1)]
-        if faults is not None:
-            for _ in range(int(cand.size)):  # one injection check per read
-                faults.check("block_read")
-        if cache is None:
-            stats.blocks_read += int(cand.size)
-        else:
-            cache.read_blocks(self.run_id, blocks.tolist(),
-                              self.block_bytes, stats)
-        if paranoid:
-            for b in np.unique(blocks):
-                if not self.verify_block(int(b)):
-                    raise CorruptionError(self.run_id, int(b))
-        inb = idx < len(self)
-        hit = np.zeros(cand.size, dtype=bool)
-        hit[inb] = self.keys[idx[inb]] == keys[cand][inb]
-        stats.false_positives += int(cand.size - np.count_nonzero(hit))
-        for p in np.nonzero(hit)[0]:
-            i = int(idx[p])
-            j = int(cand[p])
-            found[j] = True
-            vlen = int(self.vlens[i])
-            if vlen != TOMBSTONE_LEN:
-                values[j] = bytes(self.vals[i, :vlen])
+        with span("lsm.fence_search", run=run, keys=m):
+            idx = np.searchsorted(self.keys, keys[cand])
+            blocks = self.block_of[np.minimum(idx, len(self) - 1)]
+        with span("lsm.block_read", run=run, blocks=m):
+            if faults is not None:
+                for _ in range(m):  # one injection check per read
+                    faults.check("block_read")
+            if cache is None:
+                stats.blocks_read += m
+            else:
+                cache.read_blocks(self.run_id, blocks.tolist(),
+                                  self.block_bytes, stats)
+            if paranoid:
+                for b in np.unique(blocks):
+                    if not self.verify_block(int(b)):
+                        raise CorruptionError(self.run_id, int(b))
+        with span("lsm.value_copy", run=run, keys=m):
+            inb = idx < len(self)
+            hit = np.zeros(m, dtype=bool)
+            hit[inb] = self.keys[idx[inb]] == keys[cand][inb]
+            stats.false_positives += int(m - np.count_nonzero(hit))
+            for p in np.nonzero(hit)[0]:
+                i = int(idx[p])
+                j = int(cand[p])
+                found[j] = True
+                vlen = int(self.vlens[i])
+                if vlen != TOMBSTONE_LEN:
+                    values[j] = bytes(self.vals[i, :vlen])
         return found, values
 
     def values_at(self, rows: np.ndarray) -> List[Optional[bytes]]:
@@ -490,52 +498,55 @@ def merge_runs(runs: Sequence[SortedRun], bits_per_key: float,
     for r in runs:
         stats.blocks_read += r.n_blocks
     lens = [len(r) for r in runs]
-    offs = np.zeros(len(runs) + 1, dtype=np.int64)
-    np.cumsum(lens, out=offs[1:])
-    seqs_cat = runs[0].seqs if len(runs) == 1 else \
-        np.concatenate([r.seqs for r in runs])
-    # Huffman-ordered tournament: always merge the two smallest nodes, so a
-    # dominant run (the usual dst level) joins only the final merges and
-    # total element moves stay near the entropy bound.
-    heap = [(len(r), i, (r.keys, np.arange(offs[i], offs[i + 1],
-                                           dtype=np.int64)))
-            for i, r in enumerate(runs)]
-    heapq.heapify(heap)
-    tick = len(runs)
-    while len(heap) > 1:
-        _, ia, a = heapq.heappop(heap)
-        _, ib, b = heapq.heappop(heap)
-        if ib < ia:          # keep earlier-run-first orientation for ties
-            a, b = b, a
-        merged = _merge_pair(a, b, seqs_cat, pair_merge)
-        heapq.heappush(heap, (merged[0].size, tick, merged))
-        tick += 1
-    keys, gid = heap[0][2]
-    vlens_cat = runs[0].vlens if len(runs) == 1 else \
-        np.concatenate([r.vlens for r in runs])
-    vlens = vlens_cat[gid]
-    if drop_tombstones and keys.size:
-        live = vlens != TOMBSTONE_LEN
-        keys, vlens, gid = keys[live], vlens[live], gid[live]
-    seqs = seqs_cat[gid]
-    # Winner values move in two bulk passes (concat + one row gather),
-    # against the scalar oracle's concat + full permute + keep-mask three;
-    # only sources narrower than vmax need padding first.
-    vmax = max((r.vals.shape[1] if r.vals.ndim == 2 else 0) for r in runs)
-    if vmax == 0:
-        vals = np.zeros((keys.size, 0), dtype=np.uint8)
-    else:
-        mats = []
-        for r in runs:
-            v = r.vals if r.vals.ndim == 2 else r.vals.reshape(len(r), 0)
-            if v.shape[1] < vmax:
-                v = np.pad(v, ((0, 0), (0, vmax - v.shape[1])))
-            mats.append(v)
-        vals_cat = mats[0] if len(mats) == 1 else np.concatenate(mats)
-        vals = vals_cat[gid]
-    out = SortedRun(keys, seqs, vlens, vals, bits_per_key=bits_per_key,
-                    block_size=block_size, key_bytes=key_bytes,
-                    hash_fn=bloom_hash)
+    with span("lsm.merge", runs=len(runs), entries=sum(lens)):
+        offs = np.zeros(len(runs) + 1, dtype=np.int64)
+        np.cumsum(lens, out=offs[1:])
+        seqs_cat = runs[0].seqs if len(runs) == 1 else \
+            np.concatenate([r.seqs for r in runs])
+        # Huffman-ordered tournament: always merge the two smallest nodes,
+        # so a dominant run (the usual dst level) joins only the final
+        # merges and total element moves stay near the entropy bound.
+        heap = [(len(r), i, (r.keys, np.arange(offs[i], offs[i + 1],
+                                               dtype=np.int64)))
+                for i, r in enumerate(runs)]
+        heapq.heapify(heap)
+        tick = len(runs)
+        while len(heap) > 1:
+            _, ia, a = heapq.heappop(heap)
+            _, ib, b = heapq.heappop(heap)
+            if ib < ia:          # keep earlier-run-first orientation for ties
+                a, b = b, a
+            merged = _merge_pair(a, b, seqs_cat, pair_merge)
+            heapq.heappush(heap, (merged[0].size, tick, merged))
+            tick += 1
+        keys, gid = heap[0][2]
+        vlens_cat = runs[0].vlens if len(runs) == 1 else \
+            np.concatenate([r.vlens for r in runs])
+        vlens = vlens_cat[gid]
+        if drop_tombstones and keys.size:
+            live = vlens != TOMBSTONE_LEN
+            keys, vlens, gid = keys[live], vlens[live], gid[live]
+        seqs = seqs_cat[gid]
+        # Winner values move in two bulk passes (concat + one row gather),
+        # against the scalar oracle's concat + full permute + keep-mask
+        # three; only sources narrower than vmax need padding first.
+        vmax = max((r.vals.shape[1] if r.vals.ndim == 2 else 0)
+                   for r in runs)
+        if vmax == 0:
+            vals = np.zeros((keys.size, 0), dtype=np.uint8)
+        else:
+            mats = []
+            for r in runs:
+                v = r.vals if r.vals.ndim == 2 else r.vals.reshape(len(r), 0)
+                if v.shape[1] < vmax:
+                    v = np.pad(v, ((0, 0), (0, vmax - v.shape[1])))
+                mats.append(v)
+            vals_cat = mats[0] if len(mats) == 1 else np.concatenate(mats)
+            vals = vals_cat[gid]
+    with span("lsm.run_build", entries=int(keys.size)):
+        out = SortedRun(keys, seqs, vlens, vals, bits_per_key=bits_per_key,
+                        block_size=block_size, key_bytes=key_bytes,
+                        hash_fn=bloom_hash)
     return _account_merge_output(out, stats)
 
 

@@ -168,20 +168,22 @@ class CompactJob:
     task's captured ``src_run_ids`` are still validated by ``_apply`` as the
     discipline check.  Returns another CompactJob while the tree is
     unshaped — the scheduler front-queues it, keeping all compactions of a
-    flush ahead of the next flush.
+    flush ahead of the next flush.  ``cause`` is the id of the memtable
+    whose flush started the chain (the compaction spans carry it).
     """
 
-    __slots__ = ("last_task", "retries")
+    __slots__ = ("last_task", "retries", "cause")
 
-    def __init__(self):
+    def __init__(self, cause: int = 0):
         self.last_task = None
         self.retries = 0
+        self.cause = cause
 
     def run(self, store) -> Optional["CompactJob"]:
-        task = store._bg_compact_one()
+        task = store._bg_compact_one(self.cause)
         self.last_task = task
         if task is not None:
-            return CompactJob()
+            return CompactJob(self.cause)
         # Tree is shaped: this worker just paid for the sort work a range
         # view reuses, so refresh the view here (DESIGN.md §13) — the
         # foreground write path never rebuilds.  No-op unless the store has

@@ -6,7 +6,7 @@ a lifetime counter in :class:`~repro.core.types.IOStats` — no way to see
 it.  The LSM survey (Luo & Carey) makes the point that stall/compaction/cache
 telemetry is what separates a tunable production store from a benchmark demo,
 and the planned workload-adaptive tuner ("How to Grow an LSM-tree") needs
-exactly these runtime signals as its input.  Three pieces:
+exactly these runtime signals as its input.  Four pieces:
 
 ``LatencyHistogram``
     Log-bucketed (2 buckets per octave — bucket edges at powers of sqrt(2),
@@ -19,9 +19,9 @@ exactly these runtime signals as its input.  Three pieces:
     A bounded ring buffer of timestamped engine lifecycle events (flush and
     compaction start/end, slowdown/stall enter/exit, view rebuilds, cache
     eviction pressure, shard snapshot retries, background failures), with
-    ``dump()``/``since(cursor)`` for incremental consumption and a
-    human-readable ``timeline()`` report.  End events carry ``t0``/``dur_ns``
-    so consumers can rebuild intervals without pairing start/end records.
+    ``dump()``/``since(cursor)`` for incremental consumption.  End events
+    carry ``t0``/``dur_ns`` so consumers can rebuild intervals without
+    pairing start/end records.
 
 ``Telemetry``
     The facade a store carries via ``LSMConfig.telemetry`` (``None`` by
@@ -35,6 +35,18 @@ exactly these runtime signals as its input.  Three pieces:
     path.  All timestamps are ``time.perf_counter_ns()`` so histogram samples
     and trace events share one monotonic clock.
 
+``span``
+    A named host span in the JAX profiler's trace (DESIGN.md §14): while a
+    ``jax.profiler`` session records, ``span(name, **args)`` opens a
+    ``jax.profiler.TraceAnnotation`` (TraceMe), so the store's own spans
+    land in the same ``.xplane.pb`` as the device's programs, on the
+    profiler's clock.  With no session it returns one shared no-op
+    context.  It never imports jax: a process that has not imported jax
+    cannot be recording, so a store whose device routes are off stays free
+    of jax.  The engine opens spans at the sites that also charge
+    ``IOStats`` timers and record the histograms above, so one timing
+    feeds all three.
+
 Sharded aggregation is free by construction: the facade installs one live
 ``LSMConfig`` on every shard, so all shards record into the same
 ``Telemetry`` object (events may carry a ``shard`` field where the emitter
@@ -42,7 +54,9 @@ knows it).
 """
 from __future__ import annotations
 
+import contextlib
 import math
+import sys
 import threading
 import time
 from collections import deque
@@ -51,13 +65,41 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 __all__ = ["LatencyHistogram", "EventTrace", "TraceEvent", "Telemetry",
-           "TelemetrySnapshot", "TelemetryWindow", "OP_CLASSES"]
+           "TelemetrySnapshot", "TelemetryWindow", "OP_CLASSES", "span",
+           "tracing"]
 
 # Per-op-class latency histograms the engine records (benchmarks may add
 # their own classes; the Telemetry facade accepts any string key).
 OP_CLASSES = ("get", "multi_get", "put", "put_batch", "write_batch",
               "scan", "seek", "flush", "compaction", "view_rebuild",
               "wal_fsync", "stall", "rebalance", "scrub")
+
+_NO_SPAN = contextlib.nullcontext()
+# jax.profiler.TraceAnnotation.is_enabled, memoized once some caller has
+# imported jax (this module never imports it): the check runs on every
+# write, and the memo halves its cost.
+_is_enabled = None
+
+
+def tracing() -> bool:
+    """True while a ``jax.profiler`` session records host spans."""
+    global _is_enabled
+    if _is_enabled is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        annotation = getattr(profiler, "TraceAnnotation", None)
+        if annotation is None:
+            return False
+        _is_enabled = annotation.is_enabled
+    return _is_enabled()
+
+
+def span(name: str, **args):
+    """A profiler host span named ``name`` carrying ``args``, or the shared
+    no-op context when no profiler session records."""
+    if not tracing():
+        return _NO_SPAN
+    return sys.modules["jax"].profiler.TraceAnnotation(name, **args)
+
 
 _SQRT2 = math.sqrt(2.0)
 # Octaves 0..42 cover 1 ns .. 2^42 ns (~73 min) at 2 buckets/octave;
@@ -292,23 +334,6 @@ class EventTrace:
             evs = [e for e in self._buf if e.seq > cursor]
             return evs, self._seq
 
-    def timeline(self, limit: Optional[int] = None) -> str:
-        """Human-readable timeline (ms relative to the oldest buffered
-        event), newest-last.  ``limit`` keeps only the last N lines."""
-        evs = self.dump()
-        if limit is not None:
-            evs = evs[-limit:]
-        if not evs:
-            return "(no events)"
-        t_base = evs[0].ts_ns
-        lines = []
-        for e in evs:
-            kv = " ".join(f"{k}={v}" for k, v in e.fields.items()
-                          if k not in ("t0",))
-            lines.append(f"{(e.ts_ns - t_base) / 1e6:12.3f} ms "
-                         f"#{e.seq:<6d} {e.kind:<18s} {kv}")
-        return "\n".join(lines)
-
 
 class TelemetrySnapshot:
     """Point-in-time capture for windowed-delta sensing (DESIGN.md §17):
@@ -431,15 +456,3 @@ class Telemetry:
         keys = [k for k in OP_CLASSES if k in hs] + \
             sorted(k for k in hs if k not in OP_CLASSES)
         return {k: hs[k].to_dict() for k in keys}
-
-    def report(self, trace_limit: int = 40) -> str:
-        """Human-readable report: percentile table + trace timeline tail."""
-        rows = ["op                 count      p50_us      p99_us     "
-                "p999_us      max_us"]
-        for op, d in self.summary().items():
-            rows.append(f"{op:<16s}{d['count']:>8d} {d['p50_ns']/1e3:>11.1f} "
-                        f"{d['p99_ns']/1e3:>11.1f} {d['p999_ns']/1e3:>11.1f} "
-                        f"{d['max_ns']/1e3:>11.1f}")
-        return ("\n".join(rows) + "\n\n-- trace (last "
-                f"{trace_limit} events) --\n" + self.trace.timeline(
-                    limit=trace_limit))

@@ -68,6 +68,24 @@ class IOStats:
                                   # (bounded exponential backoff, §16.3)
     bg_gave_up: int = 0           # background jobs abandoned after the
                                   # retry budget — store degrades read-only
+    # Timers at the store's span boundaries (DESIGN.md §14); wall times are
+    # time.perf_counter_ns, CPU times the working thread's thread_time_ns.
+    multi_get_ns: int = 0         # wall time inside multi_get
+    probe_calls: int = 0          # device bloom-probe calls, one per run
+    probe_ns: int = 0             # wall time inside them: bitset upload,
+                                  # launches and read-back
+    flush_queue_ns: int = 0       # rotation to the start of that
+                                  # memtable's flush (async mode)
+    flush_ns: int = 0             # wall time of flushes: run build, commit
+    flush_cpu_ns: int = 0         # CPU time of the same work
+    compaction_ns: int = 0        # wall time of compactions: merge, run
+                                  # build, commit
+    compaction_cpu_ns: int = 0    # CPU time of the same work
+    entry_ns: int = 0             # wall time inside the device hash pass
+                                  # and merge entries that runs are built by
+    scan_ns: int = 0              # wall time inside scan
+    scan_seek_ns: int = 0         # of which positioning the merging
+                                  # iterator's cursors
 
     def write_amplification(self) -> float:
         """Average number of times each flushed byte was rewritten."""

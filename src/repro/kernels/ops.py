@@ -11,6 +11,13 @@ batches.
 Pallas kernels run compiled on a TPU and in interpret mode on the CPU;
 :func:`interpret_mode` decides which from the default backend, and every
 wrapper asks it — no caller passes a flag.
+
+The three store entries open ``kernel.*`` profiler spans around their host
+phases (``repro.core.telemetry.span``: no-ops unless a ``jax.profiler``
+session records), so a trace shows which host work sits between device
+programs.  The jitted programs keep the names the benchmark's trace
+reduction reads: ``jit_bloom_probe``, ``jit_hash_pair`` and
+``jit_bitonic_merge_pallas``.
 """
 from __future__ import annotations
 
@@ -18,6 +25,8 @@ from typing import Tuple
 
 import jax
 import numpy as np
+
+from repro.core.telemetry import span
 
 from .bloom_probe import bloom_probe as _bloom_probe
 from .bloom_probe import hash_pair as _kernel_hash_pair
@@ -81,12 +90,17 @@ def bloom_probe_filter(bf, keys) -> np.ndarray:
     n = keys.size
     if bf.k == 0 or n == 0:
         return np.ones(n, dtype=bool)
-    bits = jax.device_put(_pad(bf.bits, _bucket(bf.bits.size,
-                                                 PROBE_MIN_WORDS)))
+    words = _bucket(bf.bits.size, PROBE_MIN_WORDS)
+    with span("kernel.probe_upload", words=words):
+        bits = jax.device_put(_pad(bf.bits, words))
     m_bits, k = np.uint32(bf.m_bits), np.int32(bf.k)
-    outs = [_probe_jit(*split_u64(_pad(keys[i:i + PROBE_BATCH], PROBE_BATCH)),
-                       bits, m_bits, k) for i in range(0, n, PROBE_BATCH)]
-    return np.concatenate([np.asarray(o) for o in outs])[:n]
+    with span("kernel.probe_launch", keys=n, words=words):
+        outs = [_probe_jit(*split_u64(_pad(keys[i:i + PROBE_BATCH],
+                                           PROBE_BATCH)),
+                           bits, m_bits, k)
+                for i in range(0, n, PROBE_BATCH)]
+    with span("kernel.probe_readback", keys=n):
+        return np.concatenate([np.asarray(o) for o in outs])[:n]
 
 
 _hash_jit = jax.jit(_kernel_hash_pair)
@@ -103,9 +117,10 @@ def bloom_build_hashes(keys) -> Tuple[np.ndarray, np.ndarray]:
     """
     keys = np.asarray(keys, dtype=np.uint64)
     n = keys.size
-    lo, hi = split_u64(_pad(keys, _bucket(n, HASH_MIN_BATCH)))
-    h1, h2 = _hash_jit(lo, hi)
-    return np.asarray(h1)[:n], np.asarray(h2)[:n]
+    with span("kernel.hash_pass", entries=n):
+        lo, hi = split_u64(_pad(keys, _bucket(n, HASH_MIN_BATCH)))
+        h1, h2 = _hash_jit(lo, hi)
+        return np.asarray(h1)[:n], np.asarray(h2)[:n]
 
 
 def _to_u64_order(keys: np.ndarray) -> np.ndarray:
@@ -168,40 +183,47 @@ def merge_runs_tiled(keys_a: np.ndarray, keys_b: np.ndarray,
     ``tile`` must be a power of two of at least 64.
     """
     out_dtype = keys_a.dtype
-    keys_a = _to_u64_order(np.ascontiguousarray(keys_a))
-    keys_b = _to_u64_order(np.ascontiguousarray(keys_b))
-    # Diagonal spacing = tile: merge-path guarantees each cell consumes at
-    # most `tile` from either input; pads sort to the back (lane maxima), so
-    # each cell's first `consumed` outputs are exact.
-    bounds_a, bounds_b = merge_path_partition(keys_a, keys_b, tile)
-    n_tiles = len(bounds_a) - 1
-    n_rows = _bucket(n_tiles, ROWS)
-    width = 2 * tile
-    # pad payload 0xFFFFFFFF: sorts after every real source index, so the
-    # payload tie-break keeps pads strictly behind real entries even when a
-    # real key equals the dtype maximum
-    rows = [np.full((n_rows, width), 0xFFFFFFFF, dtype=np.uint32)
-            for _ in range(3)]
-    for keys, bounds, flag in ((keys_a, bounds_a, 0), (keys_b, bounds_b, _SIGN)):
-        n = len(keys)
-        if n == 0:
-            continue
-        idx = np.arange(n, dtype=np.int64)
-        t_of = np.searchsorted(bounds, idx, side="right") - 1
-        off = idx - bounds[t_of]
-        if flag:                      # B fills its half of the row reversed
-            off = width - 1 - off
-        rows[0][t_of, off] = (keys >> np.uint64(32)).astype(np.uint32)
-        rows[1][t_of, off] = (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-        rows[2][t_of, off] = idx.astype(np.uint32) | flag
-    ohi, olo, op = (np.asarray(r)[:n_tiles] for r in _merge_jit(
-        *(_signed_lane(r) for r in rows), interpret=interpret_mode()))
-    # strip padding: valid entries per cell sit at the front
-    cnt = np.diff(bounds_a) + np.diff(bounds_b)
-    keep = np.arange(width)[None, :] < cnt[:, None]
-    merged = (_unsigned_lane(ohi[keep]).astype(np.uint64) << np.uint64(32)) \
-        | _unsigned_lane(olo[keep])
-    return _from_u64_order(merged, out_dtype), _unsigned_lane(op[keep])
+    entries = len(keys_a) + len(keys_b)
+    with span("kernel.merge_pack", entries=entries):
+        keys_a = _to_u64_order(np.ascontiguousarray(keys_a))
+        keys_b = _to_u64_order(np.ascontiguousarray(keys_b))
+        # Diagonal spacing = tile: merge-path guarantees each cell consumes
+        # at most `tile` from either input; pads sort to the back (lane
+        # maxima), so each cell's first `consumed` outputs are exact.
+        bounds_a, bounds_b = merge_path_partition(keys_a, keys_b, tile)
+        n_tiles = len(bounds_a) - 1
+        n_rows = _bucket(n_tiles, ROWS)
+        width = 2 * tile
+        # pad payload 0xFFFFFFFF: sorts after every real source index, so
+        # the payload tie-break keeps pads strictly behind real entries even
+        # when a real key equals the dtype maximum
+        rows = [np.full((n_rows, width), 0xFFFFFFFF, dtype=np.uint32)
+                for _ in range(3)]
+        for keys, bounds, flag in ((keys_a, bounds_a, 0),
+                                   (keys_b, bounds_b, _SIGN)):
+            n = len(keys)
+            if n == 0:
+                continue
+            idx = np.arange(n, dtype=np.int64)
+            t_of = np.searchsorted(bounds, idx, side="right") - 1
+            off = idx - bounds[t_of]
+            if flag:                  # B fills its half of the row reversed
+                off = width - 1 - off
+            rows[0][t_of, off] = (keys >> np.uint64(32)).astype(np.uint32)
+            rows[1][t_of, off] = (keys & np.uint64(0xFFFFFFFF)).astype(
+                np.uint32)
+            rows[2][t_of, off] = idx.astype(np.uint32) | flag
+    with span("kernel.merge_launch", entries=entries, rows=n_rows):
+        out = _merge_jit(*(_signed_lane(r) for r in rows),
+                         interpret=interpret_mode())
+    with span("kernel.merge_unpack", entries=entries):
+        ohi, olo, op = (np.asarray(r)[:n_tiles] for r in out)
+        # strip padding: valid entries per cell sit at the front
+        cnt = np.diff(bounds_a) + np.diff(bounds_b)
+        keep = np.arange(width)[None, :] < cnt[:, None]
+        merged = (_unsigned_lane(ohi[keep]).astype(np.uint64)
+                  << np.uint64(32)) | _unsigned_lane(olo[keep])
+        return _from_u64_order(merged, out_dtype), _unsigned_lane(op[keep])
 
 
 _paged_attention_jit = jax.jit(paged_attention_pallas,

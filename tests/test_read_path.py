@@ -84,8 +84,11 @@ def test_multi_get_matches_scalar_get(policy, c):
     assert batch == scalar
     assert scalar == [oracle.get(int(k)) for k in queries]
     # reads don't mutate the tree: accounting must match field by field
+    # (*_ns fields are wall-clock timers)
     for f in dataclasses.fields(d_scalar):
-        assert getattr(d_scalar, f.name) == getattr(d_batch, f.name), f.name
+        if not f.name.endswith("_ns"):
+            assert getattr(d_scalar, f.name) == getattr(d_batch, f.name), \
+                f.name
     # snapshot reads
     assert db.multi_get(queries, snapshot=snap) == \
         [snap_oracle.get(int(k)) for k in queries]
@@ -220,9 +223,14 @@ def test_pallas_bloom_differential_bit_for_bit_same_batches():
     d_pallas = db.stats.delta(s1)
     assert pallas_results == numpy_results
     assert numpy_results == [[oracle.get(int(k)) for k in b] for b in batches]
-    # identical filter decisions => identical accounting, field by field
+    # identical filter decisions => identical accounting, field by field,
+    # but for the wall-clock timers (*_ns) and the count of device probe
+    # calls, which only the device route makes
     for f in dataclasses.fields(d_numpy):
-        assert getattr(d_numpy, f.name) == getattr(d_pallas, f.name), f.name
+        if not f.name.endswith("_ns") and f.name != "probe_calls":
+            assert getattr(d_numpy, f.name) == getattr(d_pallas, f.name), \
+                f.name
+    assert d_numpy.probe_calls == 0 < d_pallas.probe_calls
 
 
 # ------------------------------------------- tombstone-dense range scans (§3)
