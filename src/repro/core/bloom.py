@@ -75,9 +75,12 @@ class BloomFilter:
 
     ``bits`` is a uint32 word array with m_bits == 32 * len(bits), the exact
     layout the device probe (``kernels.ops.bloom_probe_filter``) consumes.
+    A filter never changes after it is built, so the device probe keeps its
+    padded copy of ``bits`` in ``device_bits`` (None until the first device
+    probe); it is freed with the filter.
     """
 
-    __slots__ = ("m_bits", "k", "bits", "n_keys")
+    __slots__ = ("m_bits", "k", "bits", "n_keys", "device_bits")
 
     def __init__(self, keys: np.ndarray, bits_per_key: float, hash_fn=None):
         """``hash_fn(keys) -> (h1, h2)`` optionally reroutes the hash pass
@@ -86,6 +89,7 @@ class BloomFilter:
         :func:`hash_pair` so numpy and VPU probes agree on the bitset."""
         n = int(keys.size)
         self.n_keys = n
+        self.device_bits = None
         if n == 0 or bits_per_key <= 0:
             # Degenerate filter: answers "maybe" for everything (FPR = 1).
             self.m_bits = 0

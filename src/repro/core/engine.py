@@ -1281,7 +1281,8 @@ class LSMStore:
     def _bloom_probe_fn(self):
         """The device batched-probe route (``kernels.ops.bloom_probe_filter``)
         when ``use_pallas_bloom`` is on, else None (numpy probes).  Each
-        call counts ``probe_calls`` and ``probe_ns``."""
+        call counts ``probe_calls`` and ``probe_ns``, and ``probe_uploads``
+        when it had to upload the filter's bitset to the device."""
         if not self.config.use_pallas_bloom:
             return None
         from repro.kernels import ops
@@ -1289,9 +1290,12 @@ class LSMStore:
 
         def probe(bf, keys):
             t0 = time.perf_counter_ns()
+            resident = bf.device_bits is not None
             out = ops.bloom_probe_filter(bf, keys)
             st = hub.local()
             st.probe_calls += 1
+            if not resident and bf.device_bits is not None:
+                st.probe_uploads += 1
             st.probe_ns += time.perf_counter_ns() - t0
             return out
         return probe

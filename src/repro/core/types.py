@@ -72,6 +72,8 @@ class IOStats:
     # time.perf_counter_ns, CPU times the working thread's thread_time_ns.
     multi_get_ns: int = 0         # wall time inside multi_get
     probe_calls: int = 0          # device bloom-probe calls, one per run
+    probe_uploads: int = 0        # of which uploaded the filter's bitset
+                                  # (its first device probe)
     probe_ns: int = 0             # wall time inside them: bitset upload,
                                   # launches and read-back
     flush_queue_ns: int = 0       # rotation to the start of that

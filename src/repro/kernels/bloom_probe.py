@@ -11,9 +11,11 @@ It is a plain jitted XLA function, not a Pallas kernel: Mosaic lowers only
 2-D gathers within a vreg, so a random word gather over a whole filter
 cannot be written there, while XLA's gather reads it straight from HBM with
 no cap on filter size.  ``m_bits`` and the hash count are operands, so the
-bitset can be padded to a bucketed length (``ops.bloom_probe_filter``)
-without changing any bit position, and one compile serves every level's
-hash count.
+bitset can be padded to a bucketed length without changing any bit
+position, and one compile serves every level's hash count.  The caller
+(``ops.bloom_probe_filter``) pads the keys to a power-of-two bucket sized
+to the batch and passes a bitset that stays on the device for its filter's
+lifetime, so a launch carries the batch's keys and no bitset copy.
 """
 from __future__ import annotations
 

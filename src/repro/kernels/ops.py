@@ -3,10 +3,11 @@
 The store calls three of them: :func:`bloom_probe_filter` (batched point
 reads), :func:`bloom_build_hashes` (filter builds) and
 :func:`merge_runs_tiled` (compaction).  Each pads its inputs to a bucketed
-shape (powers of two above a floor; the probe takes its keys in fixed
-chunks), so each entry compiles once per bucket of one size axis: the
-count grows with the log of the data size, not with the number of runs or
-batches.
+shape (powers of two above a floor; the probe's keys also below a cap,
+beyond which they go in chunks of the cap), so the compiles grow with the
+log of the data and batch sizes, not with the number of runs or batches.
+The probe keeps each filter's padded bitset on the device once it has
+been uploaded: filters never change after they are built.
 
 Pallas kernels run compiled on a TPU and in interpret mode on the CPU;
 :func:`interpret_mode` decides which from the default backend, and every
@@ -34,7 +35,9 @@ from .flash_attention import flash_attention_pallas
 from .merge_path import ROWS, bitonic_merge_pallas, merge_path_partition
 from .paged_attention import paged_attention_pallas
 
-PROBE_BATCH = 1 << 16       # keys per probe launch (the last chunk padded)
+PROBE_BATCH = 1 << 16       # most keys per probe launch (larger batches
+                            # go in chunks of this, the last one padded)
+PROBE_MIN_KEYS = 1 << 10    # fewest keys per probe launch
 PROBE_MIN_WORDS = 1 << 12   # smallest padded bitset (16 KiB)
 HASH_MIN_BATCH = 1024       # smallest padded hash batch
 _SIGN = np.uint32(1 << 31)
@@ -81,24 +84,30 @@ def bloom_probe_filter(bf, keys) -> np.ndarray:
     so this returns bit-identical answers to ``bf.may_contain`` — it is the
     engine's device route for batched point reads (DESIGN.md §3).  The
     bitset is padded to a bucketed length (the pad words are never indexed:
-    positions stay below ``bf.m_bits``) and uploaded once; the keys go in
-    chunks of ``PROBE_BATCH``, the last one padded, so the only shape that
-    varies between compiles is the bitset's bucket.  All chunks are launched
-    before any result is read back.
+    positions stay below ``bf.m_bits``), uploaded on the filter's first
+    device probe and kept on the filter (``bf.device_bits``) for its
+    lifetime, so later probes upload nothing.  The keys are padded to a
+    power-of-two bucket of at least ``PROBE_MIN_KEYS``; a batch above
+    ``PROBE_BATCH`` goes in chunks of ``PROBE_BATCH``, the last one padded.
+    So a launch is sized to its batch, and compiles are one per (key bucket,
+    bitset bucket) pair.  All chunks are launched before any result is read
+    back.
     """
     keys = np.asarray(keys, dtype=np.uint64)
     n = keys.size
     if bf.k == 0 or n == 0:
         return np.ones(n, dtype=bool)
-    words = _bucket(bf.bits.size, PROBE_MIN_WORDS)
-    with span("kernel.probe_upload", words=words):
-        bits = jax.device_put(_pad(bf.bits, words))
+    bits = bf.device_bits
+    if bits is None:   # racing threads may both upload: harmless
+        words = _bucket(bf.bits.size, PROBE_MIN_WORDS)
+        with span("kernel.probe_upload", words=words):
+            bits = bf.device_bits = jax.device_put(_pad(bf.bits, words))
     m_bits, k = np.uint32(bf.m_bits), np.int32(bf.k)
-    with span("kernel.probe_launch", keys=n, words=words):
-        outs = [_probe_jit(*split_u64(_pad(keys[i:i + PROBE_BATCH],
-                                           PROBE_BATCH)),
+    chunk = min(_bucket(n, PROBE_MIN_KEYS), PROBE_BATCH)
+    with span("kernel.probe_launch", keys=n, words=bits.size):
+        outs = [_probe_jit(*split_u64(_pad(keys[i:i + chunk], chunk)),
                            bits, m_bits, k)
-                for i in range(0, n, PROBE_BATCH)]
+                for i in range(0, n, chunk)]
     with span("kernel.probe_readback", keys=n):
         return np.concatenate([np.asarray(o) for o in outs])[:n]
 

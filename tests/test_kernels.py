@@ -32,18 +32,30 @@ def test_interpret_mode_follows_backend(monkeypatch, platform, interpret):
         assert ops.interpret_mode() is interpret
 
 
-@pytest.mark.parametrize("n,m_words,k", [(512, 128, 5), (2048, 1024, 7),
-                                         (4096, 64, 3),
-                                         # queries span two probe chunks
-                                         (40000, 16384, 7)])
-def test_bloom_probe_sweep(n, m_words, k):
+# (members, bitset words, hashes, batch): the batch probes the members
+# first, then random keys; None probes the members and as many random keys
+@pytest.mark.parametrize("n,m_words,k,batch", [
+    pytest.param(512, 128, 5, None, id="512-128-5"),
+    pytest.param(2048, 1024, 7, None, id="2048-1024-7"),
+    pytest.param(4096, 64, 3, None, id="4096-64-3"),
+    # queries span two probe chunks
+    pytest.param(40000, 16384, 7, None, id="40000-16384-7"),
+    # batches at the edges of the key buckets and of the 64k cap
+    *(pytest.param(2048, 1024, 7, b, id=f"batch{b}")
+      for b in (1, 1023, 1024, 1025, 65535, 65537))])
+def test_bloom_probe_sweep(n, m_words, k, batch):
     rng = np.random.default_rng(n + k)
     keys = rng.integers(0, 2**63, n, dtype=np.uint64)
     bf = _filter(keys, m_words, k)
-    queries = np.concatenate([keys, rng.integers(0, 2**63, n, np.uint64)])
+    batch = batch or 2 * n
+    members = min(n, batch)
+    queries = np.concatenate([keys[:members],
+                              rng.integers(0, 2**63, batch - members,
+                                           np.uint64)])
     got = bloom_probe_filter(bf, queries)
+    assert got.shape == (batch,)
     assert (got == bf.may_contain(queries)).all()
-    assert got[:n].all()  # no false negatives on members
+    assert got[:members].all()  # no false negatives on members
 
 
 def test_bloom_fpr_reasonable():
@@ -56,9 +68,9 @@ def test_bloom_fpr_reasonable():
 
 
 def test_device_path_compiles_once_per_bucket():
-    """Probe keys go in fixed chunks, and bitset, hash batch and tile counts
-    are padded to power-of-two buckets, so sizes that share a bucket share
-    one compile and answers stay exact."""
+    """Probe keys (1,024 at least), bitset, hash batch and tile counts are
+    padded to power-of-two buckets, so sizes that share a bucket share one
+    compile and answers stay exact."""
     rng = np.random.default_rng(4)
     keys = rng.integers(0, 2**63, 3000, dtype=np.uint64)
     before = ops._probe_jit._cache_size()

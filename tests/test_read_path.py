@@ -224,13 +224,63 @@ def test_pallas_bloom_differential_bit_for_bit_same_batches():
     assert pallas_results == numpy_results
     assert numpy_results == [[oracle.get(int(k)) for k in b] for b in batches]
     # identical filter decisions => identical accounting, field by field,
-    # but for the wall-clock timers (*_ns) and the count of device probe
-    # calls, which only the device route makes
+    # but for the wall-clock timers (*_ns) and the counts of device probe
+    # calls and bitset uploads, which only the device route makes
     for f in dataclasses.fields(d_numpy):
-        if not f.name.endswith("_ns") and f.name != "probe_calls":
+        if not f.name.endswith("_ns") and f.name not in ("probe_calls",
+                                                          "probe_uploads"):
             assert getattr(d_numpy, f.name) == getattr(d_pallas, f.name), \
                 f.name
     assert d_numpy.probe_calls == 0 < d_pallas.probe_calls
+    assert d_numpy.probe_uploads == 0 < d_pallas.probe_uploads
+
+
+def _probed_tree():
+    """A flushed tree, queries that reach every run (half of them absent),
+    and the numpy route's answers to them."""
+    db = make_db("garnering", 0.8, bits_per_key=10)
+    run_workload(db, seed=41, n_ops=1200)
+    db.flush()
+    queries = list(range(0, 1200, 3))
+    return db, queries, db.multi_get(queries)
+
+
+def test_device_probe_uploads_each_bitset_once():
+    """A filter's bitset goes to the device on its first probe and stays
+    there: two ``multi_get`` calls through the device route upload each
+    run's bitset once, and every probe call is counted."""
+    pytest.importorskip("jax")
+    db, queries, expected = _probed_tree()
+    filtered = [r for lvl in db._levels for r in lvl
+                if len(r) and r.bloom.k > 0]
+    assert len(filtered) > 1
+    db.config.use_pallas_bloom = True
+    s0 = db.stats.snapshot()
+    assert db.multi_get(queries) == expected
+    assert db.multi_get(queries) == expected
+    d = db.stats.delta(s0)
+    assert d.probe_uploads == len(filtered)
+    assert d.probe_calls == 2 * len(filtered)   # absent keys reach every run
+    assert all(r.bloom.device_bits is not None for r in filtered)
+
+
+def test_device_probe_batches_share_one_compile():
+    """After one warm-up ``multi_get``, batches of 1 to 1,024 keys against
+    the same tree fall in the key bucket it compiled: none adds a program."""
+    pytest.importorskip("jax")
+    from repro.kernels import ops
+    db, queries, expected = _probed_tree()
+    db.config.use_pallas_bloom = True
+    assert db.multi_get(queries) == expected
+    before = ops._probe_jit._cache_size()
+    rng = np.random.default_rng(5)
+    for n in (1, 2, 255, 256, 700, 1023, 1024):
+        batch = [int(k) for k in rng.integers(0, 2400, n)]
+        db.config.use_pallas_bloom = False
+        want = db.multi_get(batch)
+        db.config.use_pallas_bloom = True
+        assert db.multi_get(batch) == want
+    assert ops._probe_jit._cache_size() == before
 
 
 # ------------------------------------------- tombstone-dense range scans (§3)

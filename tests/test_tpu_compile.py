@@ -50,12 +50,13 @@ def _spec(shape, dtype, sharding):
 
 
 def test_bloom_probe_compiles_for_v5e(one_chip):
-    q = _spec((ops.PROBE_BATCH,), jnp.uint32, one_chip)
-    compiled = ops._probe_jit.lower(
-        q, q, _spec((FILTER_WORDS,), jnp.uint32, one_chip),
-        _spec((), jnp.uint32, one_chip),
-        _spec((), jnp.int32, one_chip)).compile()
-    assert compiled.memory_analysis().output_size_in_bytes == ops.PROBE_BATCH
+    for n_keys in (ops.PROBE_MIN_KEYS, ops.PROBE_BATCH):   # key bucket ends
+        q = _spec((n_keys,), jnp.uint32, one_chip)
+        compiled = ops._probe_jit.lower(
+            q, q, _spec((FILTER_WORDS,), jnp.uint32, one_chip),
+            _spec((), jnp.uint32, one_chip),
+            _spec((), jnp.int32, one_chip)).compile()
+        assert compiled.memory_analysis().output_size_in_bytes == n_keys
 
 
 def test_merge_kernel_compiles_for_v5e(one_chip):
