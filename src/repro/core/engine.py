@@ -1440,11 +1440,7 @@ class LSMStore:
                 st.view_scans += 1
                 best = view.seek(int(key), st, self.block_cache)
                 # same approximate-liveness memtable probe as the run walk
-                for mt in mems:
-                    for k, s, v in mt.scan(int(key))[:1]:
-                        if v is not None and (best is None or k < best):
-                            best = k
-                return best
+                return self._seek_mems(mems, int(key), best)
             st.view_fallbacks += 1
         for run in self._runs_newest_first(self._read_state(snapshot)):
             if len(run) == 0:
@@ -1460,10 +1456,21 @@ class LSMStore:
                 k = int(run.keys[i])
                 if best is None or k < best:
                     best = k
+        return self._seek_mems(mems, int(key), best)
+
+    @staticmethod
+    def _seek_mems(mems, key: int, best: Optional[int]) -> Optional[int]:
+        """``best`` lowered to each memtable's first live key >= ``key``.
+
+        The memtable's first entry at or past ``key`` may be a tombstone
+        with a live key after it; that live key can precede every run's
+        candidate, so the tombstone is skipped, not taken as the
+        memtable's answer."""
         for mt in mems:
-            for k, s, v in mt.scan(int(key))[:1]:
-                if v is not None and (best is None or k < best):
-                    best = k
+            k = min((k for k, _, v in mt.snapshot_items(key)
+                     if v is not None), default=None)
+            if k is not None and (best is None or k < best):
+                best = k
         return best
 
     def iterator(self, snapshot: Optional[Version] = None,

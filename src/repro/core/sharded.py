@@ -90,8 +90,9 @@ from .engine import LSMConfig, LSMStore
 from .manifest import Version
 from .run import build_run
 from .scheduler import CompactJob, WorkerBudget
+from .telemetry import span
 from .tuner import TunerStep
-from .types import KEY_DTYPE, IOStats
+from .types import KEY_DTYPE, IOStats, StatsHub
 
 _KEY_SPACE_END = 1 << 64
 _HIST_B = 32                 # buckets per shard in the load histogram (§15)
@@ -230,8 +231,9 @@ class ShardedLSMStore:
         self._ops_since_check = 0
         self._rebalance_needed = False
         self._in_rebalance = False
-        self.rebalances = 0          # completed rebalance count
-        self.migrated_entries = 0    # physical entries moved across shards
+        # The facade's own counters (routing, rebalances), one slot per
+        # thread as in the shards; ``stats`` merges them with the shards'.
+        self._stats = StatsHub()
         for si, s in enumerate(self.shards):
             # Live-config sharing: runtime toggles on the facade's config
             # reach every shard.  Construction-only fields (memtable size,
@@ -441,33 +443,62 @@ class ShardedLSMStore:
                   ) -> List[Optional[bytes]]:
         """Batched point reads: one searchsorted splits the wave, each
         shard resolves its sub-batch with its own vectorized ``multi_get``,
-        and results scatter back to the callers' positions."""
+        and results scatter back to the callers' positions.
+
+        Charges the facade's routing counters: the call's wall time, the
+        part of it outside the shard calls (``route_ns``), the keys, the
+        largest shard's part of them and the shard calls made."""
+        t0 = time.perf_counter_ns()
         keys_arr = np.asarray(list(keys), dtype=KEY_DTYPE)
-        if keys_arr.size == 0:
+        n = int(keys_arr.size)
+        if n == 0:
             return []
-        if snapshot is not None:
-            return self._multi_get_routed(self._snap_routing(snapshot),
-                                          keys_arr, snapshot)
-        while True:
-            r = self._routing
-            results = self._multi_get_routed(r, keys_arr, None)
-            if self._routing is r:
-                return results
+        shard_ns = 0
+        with span("lsm.route", keys=n):
+            while True:
+                r = self._routing if snapshot is None \
+                    else self._snap_routing(snapshot)
+                results, ns, hot, calls = self._multi_get_routed(
+                    r, keys_arr, snapshot)
+                shard_ns += ns
+                if snapshot is not None or self._routing is r:
+                    break
+        dt = time.perf_counter_ns() - t0
+        st = self._stats.local()
+        st.sharded_multi_gets += 1
+        st.sharded_multi_get_ns += dt
+        st.route_ns += dt - shard_ns
+        st.routed_keys += n
+        st.hot_shard_keys += hot
+        st.route_shards += r.n
+        st.shard_calls += calls
+        return results
 
     def _multi_get_routed(self, r: _Routing, keys_arr: np.ndarray,
                           snapshot: Optional[ShardedSnapshot]
-                          ) -> List[Optional[bytes]]:
+                          ) -> Tuple[List[Optional[bytes]], int, int, int]:
+        """One routed attempt: the answers, the nanoseconds the shards'
+        own ``multi_get`` timers charged, the largest shard's key count
+        and the number of shard calls."""
         results: List[Optional[bytes]] = [None] * int(keys_arr.size)
         sids = r.split(keys_arr)
+        shard_ns = hot = calls = 0
         for si in np.unique(sids):
+            si = int(si)
             idx = np.nonzero(sids == si)[0]
-            sub = self.shards[int(si)].multi_get(
-                keys_arr[idx], snapshot=self._shard_snap(snapshot, int(si)))
+            shard = self.shards[si]
+            timer = shard._stats.local()
+            before = timer.multi_get_ns
+            sub = shard.multi_get(keys_arr[idx],
+                                  snapshot=self._shard_snap(snapshot, si))
+            shard_ns += timer.multi_get_ns - before
+            hot = max(hot, int(idx.size))
+            calls += 1
             for j, v in zip(idx, sub):
                 results[int(j)] = v
             if snapshot is None:
-                self._note_keys(int(si), keys_arr[idx])
-        return results
+                self._note_keys(si, keys_arr[idx])
+        return results, shard_ns, hot, calls
 
     def seek(self, key: int,
              snapshot: Optional[ShardedSnapshot] = None) -> Optional[int]:
@@ -677,7 +708,8 @@ class ShardedLSMStore:
                     self._load = [v // 2 for v in loads]
                     self._load_hist = [h * 0.5 for h in self._load_hist]
                     return False
-                return self._rebalance_to(target, loads, ratio)
+                with span("lsm.rebalance", imbalance=round(ratio, 3)):
+                    return self._rebalance_to(target, loads, ratio)
             finally:
                 self._in_rebalance = False
 
@@ -747,7 +779,7 @@ class ShardedLSMStore:
                 s._scheduler.submit(CompactJob())
             else:
                 s._compact_until_quiet()
-        self.rebalances += 1
+        self._stats.local().rebalances += 1
         self._load = [0] * n
         self._load_hist = [np.zeros(_HIST_B) for _ in range(n)]
         dur = time.perf_counter_ns() - t0
@@ -833,7 +865,8 @@ class ShardedLSMStore:
             for lo, hi in ((ol, min(oh, nl)), (max(ol, nh), oh)):
                 if lo >= hi:
                     continue
-                cols = s.export_range(lo, hi)
+                with span("lsm.migrate_export", shard=si):
+                    cols = s.export_range(lo, hi)
                 if cols is None:
                     continue
                 k, sq, vl, vv = cols
@@ -842,21 +875,24 @@ class ShardedLSMStore:
                     dj = int(dj)
                     mask = dest_ids == dj
                     dst = self.shards[dj]
-                    run = build_run(k[mask], sq[mask], vl[mask], vv[mask],
-                                    bits_per_key=dst._bits_for_level(0),
-                                    drop_tombstones=True,
-                                    block_size=self.config.block_size,
-                                    key_bytes=self.config.key_bytes,
-                                    hash_fn=dst._bloom_hash_fn())
-                    if len(run) == 0:
-                        continue     # the slice was all tombstones
-                    dst.import_migrated_run(run)
+                    with span("lsm.migrate_import", src=si, dst=dj,
+                              entries=int(mask.sum())):
+                        run = build_run(k[mask], sq[mask], vl[mask],
+                                        vv[mask],
+                                        bits_per_key=dst._bits_for_level(0),
+                                        drop_tombstones=True,
+                                        block_size=self.config.block_size,
+                                        key_bytes=self.config.key_bytes,
+                                        hash_fn=dst._bloom_hash_fn())
+                        if len(run) == 0:
+                            continue     # the slice was all tombstones
+                        dst.import_migrated_run(run)
                     moves += 1
                     moved += len(run)
                     if tel is not None:
                         tel.emit("run_migrate", src=si, dst=dj,
                                  entries=len(run), bytes=run.data_bytes)
-        self.migrated_entries += moved
+        self._stats.local().migrated_entries += moved
         return moves, moved
 
     def _commit_routing(self, new: _Routing) -> None:
@@ -874,7 +910,8 @@ class ShardedLSMStore:
         shard; a crash part-way is finished by recovery's clip)."""
         for si, s in enumerate(self.shards):
             lo, hi = new.bounds(si)
-            s.strip_to_range(lo, hi)
+            with span("lsm.strip", shard=si):
+                s.strip_to_range(lo, hi)
 
     def _reassign_cache_budgets(self, loads: List[int]) -> None:
         """Re-slice the shared cache load-proportionally (1/(4N) floor).
@@ -1151,9 +1188,21 @@ class ShardedLSMStore:
     # ---------------------------------------------------------------- info
     @property
     def stats(self) -> IOStats:
-        """Aggregated counters across shards (a fresh fieldwise-summed
-        ``IOStats`` — use ``snapshot()``/``delta()`` on it as usual)."""
-        return IOStats.merge(s.stats for s in self.shards)
+        """The facade's own counters summed with every shard's (a fresh
+        fieldwise-summed ``IOStats`` — use ``snapshot()``/``delta()`` on it
+        as usual)."""
+        return IOStats.merge([self._stats.merged()]
+                             + [s.stats for s in self.shards])
+
+    @property
+    def rebalances(self) -> int:
+        """Rebalances whose migration landed."""
+        return self._stats.merged().rebalances
+
+    @property
+    def migrated_entries(self) -> int:
+        """Entries those rebalances moved across shards."""
+        return self._stats.merged().migrated_entries
 
     @property
     def shard_stats(self) -> List[dict]:

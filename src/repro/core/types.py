@@ -88,6 +88,20 @@ class IOStats:
     scan_ns: int = 0              # wall time inside scan
     scan_seek_ns: int = 0         # of which positioning the merging
                                   # iterator's cursors
+    # The sharded facade's own counters (DESIGN.md §15), charged into its
+    # own slot, which ``ShardedLSMStore.stats`` merges with the shards'.
+    sharded_multi_gets: int = 0   # multi_get calls on the facade
+    sharded_multi_get_ns: int = 0  # wall time inside them
+    route_ns: int = 0             # of which the facade's own (split,
+                                  # fan-out, gather), not the shard calls'
+    routed_keys: int = 0          # keys those calls asked for
+    hot_shard_keys: int = 0       # the largest one shard's part of each
+                                  # call, summed over calls
+    route_shards: int = 0         # the shard count each call routed over,
+                                  # summed over calls
+    shard_calls: int = 0          # shard multi_get calls they made
+    rebalances: int = 0           # rebalances whose migration landed
+    migrated_entries: int = 0     # entries they moved across shards
 
     def write_amplification(self) -> float:
         """Average number of times each flushed byte was rewritten."""

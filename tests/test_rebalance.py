@@ -82,9 +82,10 @@ def assert_reads_equal(db, oracle, rng, scans=4):
         assert db.scan(start, 50) == oracle.scan(start, 50)
     k = int(rng.integers(0, KEY_SPACE))
     live = db.scan(k, 1)
-    got = db.seek(k)
-    if live:
-        assert got is not None and k <= got <= live[0][0]
+    for store in (db, oracle):
+        got = store.seek(k)
+        if live:
+            assert got is not None and k <= got <= live[0][0]
 
 
 def no_leaked_pins(db):
@@ -100,6 +101,32 @@ def test_rebalancing_reads_identical_to_single_store(seed, shards):
     facade while reads are compared wave-by-wave against the synchronous
     single store — byte identity must hold across every split/merge/
     migration the trigger decides to make."""
+    rebalancing_vs_single_store(seed, shards)
+
+
+def test_rebalancing_reads_identical_seed_5509():
+    """The stream that found the ``seek`` fault: at its second wave both
+    stores' memtables hold a tombstone at 2126 and a live 2154, and
+    ``seek(2099)`` returned a run's later key (2279 on the single store,
+    2222 on the facade) where ``scan(2099, 1)`` gives 2154."""
+    rebalancing_vs_single_store(5509, 2)
+
+
+@pytest.mark.parametrize("views", [False, True])
+def test_seek_skips_a_memtable_tombstone(views):
+    """A memtable whose first entry at or past the key is a delete still
+    answers ``seek`` with its first live key, ahead of a run's."""
+    db = LSMStore(cfg(use_range_views=views))
+    db.put(30, b"run")
+    db.flush()
+    db.put(10, b"x")
+    db.delete(10)
+    db.put(20, b"mem")
+    assert db.scan(5, 1) == [(20, b"mem")]
+    assert db.seek(5) == 20
+
+
+def rebalancing_vs_single_store(seed, shards):
     oracle = LSMStore(cfg())
     db = make_store(sharded_cfg(shards, async_compaction=True,
                                 compaction_workers=2,
@@ -119,6 +146,69 @@ def test_rebalancing_reads_identical_to_single_store(seed, shards):
         assert db.multi_get(keys) == oracle.multi_get(keys)
         assert db.scan(0, KEY_SPACE) == oracle.scan_scalar(0, KEY_SPACE)
         assert db.total_live_entries() == oracle.total_live_entries()
+        no_leaked_pins(db)
+    finally:
+        close_quiet(db)
+
+
+def test_ordered_zipfian_multiget_stream_on_four_shards():
+    """The ``ycsb-b-multiget-4shard`` benchmark cell's stream at a small
+    size: records loaded in key order over 4 pre-split shards, then YCSB
+    B's key shares (256-key ``multi_get`` calls beside single-key updates,
+    19 of 275 calls reads) drawn zipfian 0.99 over the ordered keys, so
+    the hot set is the lowest keys, one contiguous range.  Every answer
+    equals the synchronous single store's and a dict's, the skew on shard
+    0 triggers at least one rebalance, the facade counts each key asked
+    for once, and its ``shard_skew`` lies between 1 and the shard
+    count."""
+    n, shards, batch = 4_000, 4, 256
+    rng = np.random.default_rng(20261020)
+    cdf = np.cumsum(1.0 / np.arange(1, n + 1) ** 0.99)
+    cdf /= cdf[-1]
+    oracle = LSMStore(cfg())
+    db = make_store(sharded_cfg(shards, key_space=n, async_compaction=True,
+                                compaction_workers=shards,
+                                rebalance_interval_ops=n // 2 + 48,
+                                rebalance_ratio=2.0))
+    ref = {}
+
+    def value(k, tag):
+        return tag.to_bytes(8, "little") * 12 + k.to_bytes(4, "little")
+
+    try:
+        for i in range(0, n, 500):
+            ks = list(range(i, i + 500))
+            vs = [value(k, 0) for k in ks]
+            oracle.put_batch(ks, vs)
+            db.put_batch(ks, vs)
+            ref.update(zip(ks, vs))
+        assert db.rebalances == 0, "the ordered load read as skew"
+        s0 = db.stats.snapshot()
+        asked = 0
+        for tag in range(1, 3_000):
+            if rng.random() < 19 / 275:
+                ks = np.searchsorted(cdf, rng.random(batch)).tolist()
+                want = [ref.get(k) for k in ks]
+                assert db.multi_get(ks) == want
+                assert oracle.multi_get(ks) == want
+                asked += batch
+            else:
+                k = int(np.searchsorted(cdf, rng.random()))
+                v = value(k, tag)
+                oracle.put(k, v)
+                db.put(k, v)
+                ref[k] = v
+        d = db.stats.delta(s0)
+        assert db.rebalances >= 1, "the zipfian head never triggered"
+        assert d.routed_keys == asked > 0
+        skew = (d.route_shards / d.sharded_multi_gets
+                * d.hot_shard_keys / d.routed_keys)
+        assert 1.0 <= skew <= shards
+        db.flush()
+        assert db.wait_for_quiesce(60)
+        keys = list(range(n))
+        assert db.multi_get(keys) == oracle.multi_get(keys) == \
+            [ref[k] for k in keys]
         no_leaked_pins(db)
     finally:
         close_quiet(db)

@@ -20,6 +20,8 @@
   with the device programs; no span object exists without a profiler
   session; a store with its device routes off never imports jax; the
   timers' invariants.
+* The sharded facade's spans (``lsm.route``, ``lsm.rebalance`` and the
+  migration's steps) and its routing and rebalance counters.
 """
 import glob
 import math
@@ -34,7 +36,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (EventTrace, IOStats, LatencyHistogram, LSMConfig,
-                        LSMStore, StatsHub, Telemetry, make_store)
+                        LSMStore, StatsHub, Telemetry, make_store,
+                        uniform_splitters)
 from repro.core.run import levels_bit_equal
 from repro.core.telemetry import N_BUCKETS, bucket_of
 
@@ -530,3 +533,94 @@ def test_span_timers_invariants():
     for f in ("multi_get_ns", "probe_ns", "flush_queue_ns", "flush_ns",
               "compaction_ns", "entry_ns", "scan_ns", "scan_seek_ns"):
         assert getattr(merged, f) == getattr(s, f), f
+
+
+# ------------------------------------------ the sharded facade (§14, §15)
+ROUTE_FIELDS = ("sharded_multi_gets", "sharded_multi_get_ns", "route_ns",
+                "routed_keys", "hot_shard_keys", "route_shards",
+                "shard_calls", "rebalances", "migrated_entries")
+
+
+def _sharded(n=4, **kw):
+    db = make_store(LSMConfig(memtable_bytes=1 << 12, bits_per_key=8,
+                              shards=n,
+                              shard_splitters=uniform_splitters(n, 4000),
+                              **kw))
+    for i in range(0, 4000, 3):
+        db.put(i, b"v" * 16)
+    db.flush()
+    return db
+
+
+def test_sharded_spans_nest_route_and_rebalance(tmp_path):
+    """A facade ``multi_get`` opens ``lsm.route`` around its shards'
+    ``lsm.multi_get`` spans, and a rebalance opens ``lsm.rebalance`` around
+    its export, import and strip steps, on the caller's thread."""
+    import jax
+    from jax.profiler import TraceAnnotation
+
+    db = _sharded(2)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("test"):
+            db.multi_get(list(range(0, 4000, 7)))
+            assert db.rebalance_to([1000])
+    finally:
+        jax.profiler.stop_trace()
+    spans, _ = _trace_events(tmp_path)
+    line, = [ln for ln in spans.values()
+             if any(n == "lsm.route" for n, *_ in ln)]
+    route, = [(a, b) for n, a, b, _ in line if n == "lsm.route"]
+    reads = [(a, b) for n, a, b, _ in line if n == "lsm.multi_get"]
+    assert len(reads) == 2
+    assert all(route[0] <= a <= b <= route[1] for a, b in reads)
+    reb, = [(a, b) for n, a, b, _ in line if n == "lsm.rebalance"]
+    steps = [(n, a, b, args) for n, a, b, args in line
+             if n in ("lsm.migrate_export", "lsm.migrate_import",
+                      "lsm.strip")]
+    assert {n for n, *_ in steps} == {"lsm.migrate_export",
+                                      "lsm.migrate_import", "lsm.strip"}
+    assert all(reb[0] <= a <= b <= reb[1] for _, a, b, _ in steps)
+    imports = [args for n, _, _, args in steps if n == "lsm.migrate_import"]
+    assert sum(a["entries"] for a in imports) == db.migrated_entries > 0
+
+
+def test_sharded_route_counters():
+    """The facade's counters against what its calls asked for: one
+    ``multi_get`` each, every key counted once, the largest shard's part
+    and the shards called per batch as the splitters assign them, the
+    routing time outside the shards' own ``multi_get`` time, and the
+    rebalance counters behind the ``rebalances``/``migrated_entries``
+    attributes.  A plain store charges none of them."""
+    db = _sharded(4)
+    rng = np.random.default_rng(7)
+    s0 = db.stats.snapshot()
+    shard0 = [s.stats.multi_get_ns for s in db.shards]
+    batches = [rng.integers(0, 4000, int(m)).tolist()
+               for m in rng.integers(1, 300, 12)]
+    batches.append(list(range(0, 900)))          # one shard only
+    for b in batches:
+        assert db.multi_get(b) == [b"v" * 16 if k % 3 == 0 else None
+                                   for k in b]
+    d = db.stats.delta(s0)
+    per = [np.bincount(np.searchsorted([1000, 2000, 3000], b, side="right"),
+                       minlength=4) for b in batches]
+    assert d.sharded_multi_gets == len(batches)
+    assert d.routed_keys == sum(len(b) for b in batches)
+    assert d.hot_shard_keys == sum(int(p.max()) for p in per)
+    assert d.shard_calls == sum(int((p > 0).sum()) for p in per)
+    assert d.route_shards == 4 * len(batches)
+    shard_ns = sum(s.stats.multi_get_ns - t0
+                   for s, t0 in zip(db.shards, shard0))
+    assert 0 < d.route_ns < d.sharded_multi_get_ns
+    assert d.route_ns + shard_ns == d.sharded_multi_get_ns
+    assert d.rebalances == 0 and d.migrated_entries == 0
+    assert db.rebalance_to([500, 1500, 2500])
+    assert db.stats.rebalances == db.rebalances == 1
+    assert db.stats.migrated_entries == db.migrated_entries > 0
+    plain = LSMStore(LSMConfig(memtable_bytes=1 << 12, bits_per_key=8))
+    for i in range(600):
+        plain.put(i, b"v")
+    plain.flush()
+    plain.multi_get(list(range(0, 600, 5)))
+    assert all(getattr(plain.stats, f) == 0 for f in ROUTE_FIELDS)
